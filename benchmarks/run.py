@@ -102,7 +102,8 @@ def main() -> None:
                          "at https://ui.perfetto.dev)")
     args = ap.parse_args()
 
-    from repro import experiments
+    from repro import compile_cache, experiments
+    compile_cache.enable()
 
     if args.list_scenarios:
         for name in experiments.names():

@@ -64,7 +64,9 @@ def _pool_speedup(unit_args, workers: int, serial_wall: float) -> float:
     import multiprocessing
 
     t0 = time.perf_counter()
-    with multiprocessing.get_context().Pool(workers) as pool:
+    # forkserver: workers never inherit this process's JAX backend (a
+    # forked child of a process holding the chip would share its client)
+    with multiprocessing.get_context("forkserver").Pool(workers) as pool:
         pool.starmap(_des_unit, unit_args, chunksize=1)
     pool_wall = time.perf_counter() - t0
     return max(serial_wall / max(pool_wall, 1e-9), 1.0)
@@ -104,7 +106,7 @@ def run(quick: bool = True):
                                   sres["throughput"])
     shard = sres["sharding"]
     out.append(row("vectorsim/sharded", sh_wall, len(grid),
-                   f"devices={shard['devices']} impl={shard['impl']} "
+                   f"devices={shard['devices']} "
                    f"kernel={shard['kernel']} chunk={shard['chunk']} "
                    f"{len(shard['chunks'])}chunks "
                    f"{len(grid)/max(sh_wall, 1e-9):.0f}cells/s "
@@ -179,7 +181,7 @@ def run(quick: bool = True):
         "sharded": {"wall_s": round(sh_wall, 2),
                     "cells_per_s": round(len(grid) / max(sh_wall, 1e-9), 1),
                     "device_count": shard["devices"],
-                    "impl": shard["impl"], "kernel": shard["kernel"],
+                    "kernel": shard["kernel"],
                     "chunk": shard["chunk"],
                     "chunks": [{"cells": m["cells"],
                                 "wall_s": round(m["wall_s"], 3),

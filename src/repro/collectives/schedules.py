@@ -30,19 +30,6 @@ from ..kernels.ops import pig_aggregate as pig_aggregate_op
 from ..kernels.pig_aggregate import quantize_blockwise
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis inside a shard_map/pmap context.
-
-    ``jax.lax.axis_size`` only exists in newer JAX releases; ``psum`` of the
-    constant 1 folds to the axis size as a static Python int on every
-    version, so reshapes depending on it stay shape-static.
-    """
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _flatten(x: jax.Array, mult: int):
     """Flatten to 1-D and pad to a multiple of ``mult``."""
     flat = x.reshape(-1)
@@ -71,7 +58,7 @@ def pig_allreduce(x: jax.Array, group_axis: str = "data",
     ``rotation`` (e.g. the step counter) additionally rotates which chip
     owns which shard across steps for uniform sustained link wear.
     """
-    G = _axis_size(group_axis)
+    G = jax.lax.axis_size(group_axis)
     flat, pad = _flatten(x, G)
     if rotation:
         flat = jnp.roll(flat, (rotation % G) * (flat.shape[0] // G))
@@ -100,8 +87,8 @@ def pig_allreduce_quantized(x: jax.Array, residual: Optional[jax.Array],
 
     Returns (synced, new_residual); both shaped like x.
     """
-    G = _axis_size(group_axis)
-    npods = _axis_size(pod_axis)
+    G = jax.lax.axis_size(group_axis)
+    npods = jax.lax.axis_size(pod_axis)
     flat, pad = _flatten(x, G * block)
     if residual is not None:
         flat = flat + residual.reshape(-1)

@@ -100,11 +100,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
 
-try:        # shard_map is the primary sharding path; pmap is the fallback
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:                                   # pragma: no cover
-    _shard_map = None
-
 from .messages import HEADER_BYTES, CostModel
 from .pig import partition_followers, required_per_group
 from .quorums import fast_quorum, majority
@@ -1266,14 +1261,14 @@ def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
 
 # ================================================================= sharding
 # compiled sharded runners, keyed by the full static signature (shapes,
-# step budget, device count, impl) — chunks of one sharded run hit the
-# same entry, so compile cost amortizes across the whole grid
+# step budget, device count) — chunks of one sharded run hit the same
+# entry, so compile cost amortizes across the whole grid
 _SHARD_CACHE: Dict[tuple, object] = {}
 
 
 def _run_cells_sharded(batch, steps: int, kmax: int, kind: str, breq: int,
                        faulty: bool, nb: int, kernel: str,
-                       devices, impl: str, read: bool = False):
+                       devices, read: bool = False):
     """One chunk through the device-sharded runner.  The cell axis (every
     leaf's leading axis) is split evenly across ``devices`` — cell count
     must be a multiple of the device count.  Inputs are DONATED: chunked
@@ -1282,31 +1277,18 @@ def _run_cells_sharded(batch, steps: int, kmax: int, kind: str, breq: int,
     D = len(devices)
     shapes = tuple((k,) + tuple(v.shape) + (str(np.asarray(v).dtype),)
                    for k, v in sorted(batch.items()))
-    sig = (kind, steps, kmax, breq, faulty, nb, kernel, D, impl,
-           read) + shapes
+    sig = (kind, steps, kmax, breq, faulty, nb, kernel, D, read) + shapes
     fn = _SHARD_CACHE.get(sig)
     if fn is None:
         def body(b):
             return _cells_fn(b, steps, kmax, kind, breq, faulty, nb,
                              kernel, read=read)
-        if impl == "shard_map":
-            mesh = Mesh(np.asarray(devices), ("cells",))
-            fn = jax.jit(_shard_map(body, mesh=mesh,
-                                    in_specs=PartitionSpec("cells"),
-                                    out_specs=PartitionSpec("cells"),
-                                    check_rep=False),
-                         donate_argnums=0)
-        elif impl == "pmap":
-            pfn = jax.pmap(body, devices=devices, donate_argnums=0)
-
-            def fn(b, _p=pfn, _D=D):
-                split = {k: v.reshape((_D, v.shape[0] // _D) + v.shape[1:])
-                         for k, v in b.items()}
-                out = _p(split)
-                return {k: v.reshape((-1,) + v.shape[2:])
-                        for k, v in out.items()}
-        else:
-            raise ValueError(f"impl must be shard_map|pmap, got {impl!r}")
+        mesh = Mesh(np.asarray(devices), ("cells",))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=PartitionSpec("cells"),
+                                   out_specs=PartitionSpec("cells"),
+                                   check_vma=False),
+                     donate_argnums=0)
         _SHARD_CACHE[sig] = fn
     with warnings.catch_warnings():
         # scalar per-cell inputs can never be reused for the (bigger)
@@ -1320,28 +1302,25 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
                           duration: float, warmup: float, *,
                           steps: Optional[int] = None,
                           timeline: bool = False, kernel: str = "auto",
-                          chunk: int = 4096, devices=None,
-                          impl: str = "auto") -> Dict[str, np.ndarray]:
+                          chunk: int = 4096,
+                          devices=None) -> Dict[str, np.ndarray]:
     """``simulate_grid`` scaled out: the cell grid is partitioned across
-    devices (``shard_map``; ``impl="pmap"`` fallback) and dispatched in
-    fixed-size chunks whose inputs are donated, so device memory is
-    bounded by one chunk and one compilation serves every chunk (the
-    padded-shape signature is pinned grid-wide via ``_pad_spec``).
+    devices (``jax.shard_map``) and dispatched in fixed-size chunks whose
+    inputs are donated, so device memory is bounded by one chunk and one
+    compilation serves every chunk (the padded-shape signature is pinned
+    grid-wide via ``_pad_spec``).
 
-    On this CPU-only container, multi-device execution is exercised via
+    On a CPU host, multi-device execution is exercised via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set before the
-    process imports jax); on a real GPU/TPU host the same call sharding
-    applies with no code change — device count comes from
+    process imports jax); on a TPU host the same call shards over
     ``jax.devices()``.  Per-cell results are bit-identical to
     single-device ``simulate_grid`` (cells are independent vmap lanes).
 
     Returns the ``simulate_grid`` dict plus ``out["sharding"]``: device
-    count, impl, chunk size, and per-chunk {cells, wall_s, steps} — the
+    count, kernel, chunk size, and per-chunk {cells, wall_s, steps} — the
     stream the megagrid study and the bench schema consume.
     """
     devices = list(devices if devices is not None else jax.devices())
-    if impl == "auto":
-        impl = "shard_map" if _shard_map is not None else "pmap"
     D = len(devices)
     chunk = max(chunk - chunk % D, D)
     kind = configs[0].kind
@@ -1371,7 +1350,7 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
         steps_c = steps0
         cout = _run_cells_sharded(batch, -(-steps_c // breq), spec["kmax"],
                                   kind, breq, faulty, nb, kernel, devices,
-                                  impl, read)
+                                  read)
         cout = {k: np.array(v) for k, v in cout.items()}
         csteps = np.full(chunk, steps_c, np.int32)
         while cout["exhausted"][:real].any() and steps_c < _MAX_STEPS:
@@ -1382,7 +1361,7 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
             sub = {k: v[ridx] for k, v in batch.items()}
             sub_out = _run_cells_sharded(sub, -(-steps_c // breq),
                                          spec["kmax"], kind, breq, faulty,
-                                         nb, kernel, devices, impl, read)
+                                         nb, kernel, devices, read)
             for k, v in sub_out.items():
                 cout[k][idx] = np.asarray(v)[:len(idx)]
             csteps[idx] = steps_c
@@ -1395,7 +1374,7 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
         meta.append({"cells": real, "wall_s": wall,
                      "steps": int(csteps[:real].max())})
     out["steps"] = steps_arr
-    out["sharding"] = {"devices": D, "impl": impl, "kernel": kernel,
+    out["sharding"] = {"devices": D, "kernel": kernel,
                        "chunk": chunk, "chunks": meta}
     return out
 

@@ -39,6 +39,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from .. import compile_cache
 from ..core import PigConfig, WorkloadConfig
 from ..core import vectorsim as vs
 from .runner import ARTIFACT_SCHEMA, _agg
@@ -206,14 +207,14 @@ def roofline_note(buckets: List[dict], ceilings: Dict[str, float]) -> dict:
 # ------------------------------------------------------------------ the run
 def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
                  chunk: int = 4096, kernel: str = "auto",
-                 impl: str = "auto", duration: float = 0.1,
+                 duration: float = 0.1,
                  warmup: float = 0.05, progress=print) -> dict:
     """Run the cross-product study at >= ``cells`` total grid cells and
     return the ``repro-experiments/v1`` artifact (see module docstring).
 
     Memory is bounded by ``chunk`` (sharded dispatch donates each chunk's
-    buffers); compile cost is one trace per bucket.  ``kernel`` and
-    ``impl`` pass through to ``simulate_grid_sharded``.
+    buffers); compile cost is one trace per bucket.  ``kernel`` passes
+    through to ``simulate_grid_sharded``.
     """
     import jax
 
@@ -244,7 +245,7 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             spans.append((pi, k, s0, len(grid)))
         t0 = time.perf_counter()
         out = vs.simulate_grid_sharded(cfgs, grid, duration, warmup,
-                                       chunk=chunk, kernel=kernel, impl=impl)
+                                       chunk=chunk, kernel=kernel)
         wall = time.perf_counter() - t0
         for pi, k, lo, hi in spans:
             tput = out["throughput"][lo:hi]
@@ -325,8 +326,8 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             "speedup_per_cell": round(BASELINE_PER_CELL_MS / per_cell_ms, 1),
             "device_count": int(jax.device_count()),
             "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
             "kernel": vs._resolve_kernel(kernel, "group"),
-            "impl": impl,
             "chunk": chunk,
             "duration_s": duration, "warmup_s": warmup,
             "buckets": bmeta,
@@ -343,16 +344,15 @@ def main(argv=None) -> int:
     ap.add_argument("--chunk", type=int, default=4096)
     ap.add_argument("--kernel", default="auto",
                     choices=("auto", "lax", "pallas"))
-    ap.add_argument("--impl", default="auto",
-                    choices=("auto", "shard_map", "pmap"))
     ap.add_argument("--duration", type=float, default=0.1)
     ap.add_argument("--warmup", type=float, default=0.05)
     ap.add_argument("--out", default="megagrid.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     axes = SMOKE_AXES if args.preset == "smoke" else FULL_AXES
     art = run_megagrid(args.cells, axes=axes, chunk=args.chunk,
-                       kernel=args.kernel, impl=args.impl,
-                       duration=args.duration, warmup=args.warmup)
+                       kernel=args.kernel, duration=args.duration,
+                       warmup=args.warmup)
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1, sort_keys=True)
     mg = art["megagrid"]
@@ -360,7 +360,9 @@ def main(argv=None) -> int:
           f"({mg['cells_per_s']} cells/s, {mg['per_cell_ms']} ms/cell; "
           f"{mg['speedup_per_cell']}x the committed 384-cell baseline) "
           f"-> {args.out}")
-    print(f"[megagrid] roofline: {mg['roofline']}")
+    # an op-count model against host-measured ceilings, not a chip reading
+    print(f"[megagrid] modelled roofline note (not a device measurement): "
+          f"{mg['roofline']}")
     return 0
 
 

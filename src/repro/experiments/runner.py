@@ -374,7 +374,11 @@ def run_scenarios(scenarios: Sequence[Scenario], quick: bool = True,
         # a serial run
         order = sorted(range(len(payloads)), reverse=True,
                        key=lambda i: _unit_cost_estimate(payloads[i]))
-        with multiprocessing.get_context().Pool(processes) as pool:
+        # forkserver: DES workers never inherit a JAX backend that the
+        # batch scenarios above started in this process (on a TPU host a
+        # forked child would share the parent's chip client)
+        ctx = multiprocessing.get_context("forkserver")
+        with ctx.Pool(processes) as pool:
             res = pool.map(_run_unit, [payloads[i] for i in order],
                            chunksize=1)
         results = [None] * len(payloads)

@@ -74,8 +74,8 @@ def seg_fanin_ref(vals: jax.Array, coef: jax.Array, segid: jax.Array,
     gsl = seg_start_index(first, axis=0)                   # (F,)
     posf = (jnp.arange(F) - gsl).astype(jnp.float32)
     anchor = jnp.asarray(anchor, jnp.float32).reshape(B, 1)
-    y = arr_s + jnp.maximum(coef + vcoef * (arr_s - anchor), 0.0) \
-        + md1 - posf[None, :] * c
+    y = arr_s + (jnp.maximum(coef + vcoef * (arr_s - anchor), 0.0) + md1) \
+        - posf[None, :] * c
     pref = seg_cummax(y, first_b, axis=1)
     idx = jnp.clip(gsl + kcap.astype(jnp.int32), 0, F - 1)
     return jnp.take_along_axis(pref, jnp.broadcast_to(idx[None, :], (B, F)),

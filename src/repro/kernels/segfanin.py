@@ -41,47 +41,59 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _fanin_kernel(v_ref, u_ref, s_ref, k_ref, c_ref, o_ref):
+    """One whole (B, F) burst tile per grid program (the block equals the
+    array's last two dims, the shape Mosaic accepts for any B), looping
+    over its B rows.  ``c_ref`` is the (B, 4) scalar table in SMEM."""
     f32 = jnp.float32
-    v = v_ref[...]                       # (1, F) fan-in arrivals, +inf masked
-    u = u_ref[...]                       # (1, F) segment-constant coefficient
-    sid = s_ref[...]                     # (1, F) segment id (f32, exact ints)
-    kcap = k_ref[...]                    # (1, F) per-segment threshold cap
-    sc = c_ref[...]                      # (1, 4) [vcoef, md1, c, anchor]
-    vcoef, md1, c, anchor = sc[0, 0], sc[0, 1], sc[0, 2], sc[0, 3]
-    F = v.shape[1]
-    vt = jnp.transpose(v, (1, 0))        # (F, 1): slot i down the rows
-    st = jnp.transpose(sid, (1, 0))
+    B, F = v_ref.shape
     j_idx = lax.broadcasted_iota(jnp.int32, (F, F), 1)
     i_idx = lax.broadcasted_iota(jnp.int32, (F, F), 0)
-    same = sid == st                     # (F, F): j in segment(i)
-    # j sorts before i: stable (value, index) order == lax.sort's tie-break
-    before = (v < vt) | ((v == vt) & (j_idx < i_idx))
-    rank_i = jnp.sum(jnp.where(same & before, f32(1.0), f32(0.0)),
-                     axis=1, keepdims=True)            # (F, 1) rank of i
-    rank = jnp.transpose(rank_i, (1, 0))               # (1, F) rank of j
-    y = v + jnp.maximum(u + vcoef * (v - anchor), 0.0) + md1 - rank * c
-    ok = same & (rank <= kcap) & (v < jnp.inf)
-    contrib = jnp.where(ok, jnp.broadcast_to(y, (F, F)), -jnp.inf)
-    o_ref[...] = jnp.transpose(jnp.max(contrib, axis=1, keepdims=True),
-                               (1, 0))
+
+    def row(r, carry):
+        rows = pl.ds(r, 1)
+        v = v_ref[rows, :]                   # (1, F) arrivals, +inf masked
+        u = u_ref[rows, :]                   # (1, F) segment-constant coef
+        sid = s_ref[rows, :]                 # (1, F) segment id (exact f32)
+        kcap = k_ref[rows, :]                # (1, F) per-segment cap
+        vcoef, md1 = c_ref[r, 0], c_ref[r, 1]
+        c, anchor = c_ref[r, 2], c_ref[r, 3]
+        vt = jnp.transpose(v, (1, 0))        # (F, 1): slot i down the rows
+        st = jnp.transpose(sid, (1, 0))
+        same = sid == st                     # (F, F): j in segment(i)
+        # j sorts before i: stable (value, index) order == lax.sort's order
+        before = (v < vt) | ((v == vt) & (j_idx < i_idx))
+        rank_i = jnp.sum(jnp.where(same & before, f32(1.0), f32(0.0)),
+                         axis=1, keepdims=True)        # (F, 1) rank of i
+        rank = jnp.transpose(rank_i, (1, 0))           # (1, F) rank of j
+        # summed in the lax path's order (wait + md1 first), so both paths
+        # round alike and commit the same requests
+        y = v + (jnp.maximum(u + vcoef * (v - anchor), 0.0) + md1) - rank * c
+        ok = same & (rank <= kcap) & (v < jnp.inf)
+        contrib = jnp.where(ok, jnp.broadcast_to(y, (F, F)), -jnp.inf)
+        o_ref[rows, :] = jnp.transpose(
+            jnp.max(contrib, axis=1, keepdims=True), (1, 0))
+        return carry
+
+    lax.fori_loop(0, B, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def seg_fanin_bf(vals: jax.Array, coef: jax.Array, segid: jax.Array,
                  kcap: jax.Array, scal: jax.Array,
                  interpret: bool = False) -> jax.Array:
-    """vals/coef/segid/kcap: (B, F) f32; scal: (B, 4) f32 rows of
-    [vcoef, md1, c, anchor].  Returns (B, F) f32 capped segment maxes."""
+    """vals/coef/segid/kcap: (B, F) f32, F a multiple of 128; scal: (B, 4)
+    f32 rows of [vcoef, md1, c, anchor].  Returns (B, F) f32 capped
+    segment maxes.  Under ``vmap`` the mapped axis becomes the grid."""
     B, F = vals.shape
-    spec = pl.BlockSpec((1, F), lambda b: (b, 0))
+    spec = pl.BlockSpec((B, F), lambda: (0, 0))
     return pl.pallas_call(
         _fanin_kernel,
-        grid=(B,),
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec((1, 4), lambda b: (b, 0))],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, F), jnp.float32),
         interpret=interpret,

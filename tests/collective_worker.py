@@ -12,7 +12,6 @@ import jax                                    # noqa: E402
 import jax.numpy as jnp                       # noqa: E402
 import numpy as np                            # noqa: E402
 from jax.sharding import PartitionSpec as P   # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro.collectives import (direct_allreduce, pig_allreduce,  # noqa: E402
                                pig_allreduce_quantized)
@@ -28,8 +27,8 @@ def main() -> None:
     x = jax.random.normal(key, (4, 1031), jnp.float32)    # odd size: pad path
 
     def run(fn):
-        m = shard_map(fn, mesh=mesh, in_specs=P(("pod", "data")),
-                      out_specs=P(("pod", "data")), check_rep=False)
+        m = jax.shard_map(fn, mesh=mesh, in_specs=P(("pod", "data")),
+                          out_specs=P(("pod", "data")), check_vma=False)
         return jax.jit(m)
 
     def direct(xs):
@@ -54,9 +53,10 @@ def main() -> None:
                                        pod_axis="pod", block=256)
         return y, r
 
-    y, r = jax.jit(shard_map(pigq, mesh=mesh, in_specs=P(("pod", "data")),
-                             out_specs=(P(("pod", "data")), P(("pod", "data"))),
-                             check_rep=False))(x)
+    y, r = jax.jit(jax.shard_map(
+        pigq, mesh=mesh, in_specs=P(("pod", "data")),
+        out_specs=(P(("pod", "data")), P(("pod", "data"))),
+        check_vma=False))(x)
     y = np.asarray(y)
     err = np.abs(y - want)
     step = np.abs(x).max() / 127.0
@@ -68,8 +68,8 @@ def main() -> None:
     from repro.roofline import collective_stats
 
     def stats_of(fn, out_specs=P(("pod", "data"))):
-        m = shard_map(fn, mesh=mesh, in_specs=P(("pod", "data")),
-                      out_specs=out_specs, check_rep=False)
+        m = jax.shard_map(fn, mesh=mesh, in_specs=P(("pod", "data")),
+                          out_specs=out_specs, check_vma=False)
         txt = jax.jit(m).lower(x).compile().as_text()
         return collective_stats(txt, pod_size=4)   # 8 devices / 2 pods
 

@@ -2,8 +2,8 @@
 
 Run in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=4
 so the main pytest process keeps its single-device view.  Asserts the
-sharded grid runner (both shard_map and pmap impls, chunked and not) is
-bit-identical to the single-call ``simulate_grid`` on the same cells.
+sharded grid runner (``jax.shard_map``, chunked and not) is bit-identical
+to the single-call ``simulate_grid`` on the same cells.
 """
 import os
 
@@ -25,18 +25,15 @@ def main() -> None:
             for s in range(10)]                      # 60 cells, not % 4 == 0
     want = vs.simulate_grid(cfgs, grid, 0.1, 0.05)
 
-    for impl in ("shard_map", "pmap"):
-        for chunk in (len(grid) + 4, 16):            # one chunk / many
-            got = vs.simulate_grid_sharded(cfgs, grid, 0.1, 0.05,
-                                           impl=impl, chunk=chunk)
-            sh = got["sharding"]
-            assert sh["devices"] == 4 and sh["impl"] == impl, sh
-            for key in ("throughput", "median_s", "p99_s", "committed"):
-                np.testing.assert_array_equal(
-                    np.asarray(want[key]), got[key],
-                    err_msg=f"{impl} chunk={chunk} key={key}")
-            print(f"OK {impl} chunk={chunk} "
-                  f"({len(sh['chunks'])} chunks, 4 devices)")
+    for chunk in (len(grid) + 4, 16):                # one chunk / many
+        got = vs.simulate_grid_sharded(cfgs, grid, 0.1, 0.05, chunk=chunk)
+        sh = got["sharding"]
+        assert sh["devices"] == 4, sh
+        for key in ("throughput", "median_s", "p99_s", "committed"):
+            np.testing.assert_array_equal(
+                np.asarray(want[key]), got[key],
+                err_msg=f"chunk={chunk} key={key}")
+        print(f"OK chunk={chunk} ({len(sh['chunks'])} chunks, 4 devices)")
 
     # epaxos kind through the same path
     ecfg = vs.build_config("epaxos", 5)

@@ -168,6 +168,7 @@ def _fanin_case(key, B, G, gsize, mask_per_seg=0):
 
 @pytest.mark.parametrize("B,G,gsize", [
     (1, 1, 4),        # single segment
+    (4, 3, 8),        # a 4-client burst (megagrid k=4 bucket), N=25 R=3
     (8, 4, 6),        # the production shape (N=25, R=4)
     (8, 8, 16),       # wide, pads 128 -> 128 exactly
     (3, 5, 7),        # odd everything (padding path, 35 -> 128)

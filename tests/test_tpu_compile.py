@@ -1,0 +1,87 @@
+"""The batch backend's device path, compiled for a described TPU v5e.
+
+Nothing runs here: each test lowers and compiles for a ``v5e:2x2``
+topology that is described, not attached.  Interpret mode (every other
+kernel test) cannot show what the chip's compiler refuses — block shapes
+off the (8, 128) tiling, kernels past the scoped fast-memory limit — so
+these compiles guard the fan-in kernel at the widths the model uses
+(F = 24 -> 128 at N=25, F = 1024 at N=1025) and the whole scan step
+around it.  Code that asks ``jax.default_backend()`` still sees the CPU,
+so the group step is steered onto the native kernel by patching
+``kernels.ops._interpret`` inside the test.
+"""
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from repro.core import PigConfig, WorkloadConfig  # noqa: E402
+from repro.core import vectorsim as vs  # noqa: E402
+from repro.kernels import ops  # noqa: E402
+from repro.kernels.segfanin import seg_fanin_bf  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2, with JAX's persistent cache off
+    (a compile for an absent chip is written but can never be read)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs in /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001  (no libtpu / no topology)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _structs(batch, sharding):
+    return {k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype,
+                                    sharding=sharding)
+            for k, v in batch.items()}
+
+
+def _compile_step(one_chip, cfgs, grid, kernel):
+    batch, kind, kmax = vs._stack_cells(cfgs, grid, 0.2, 0.1)
+    breq = min(8, kmax) if kind == "group" else 1
+    lowered = vs._run_cells.lower(_structs(batch, one_chip), steps=64,
+                                  kmax=kmax, kind=kind, breq=breq,
+                                  kernel=kernel)
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("B,F", [(4, 128), (8, 128), (8, 1024)])
+def test_seg_fanin_compiles_native(one_chip, B, F):
+    tile = jax.ShapeDtypeStruct((B, F), jnp.float32, sharding=one_chip)
+    scal = jax.ShapeDtypeStruct((B, 4), jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda v, u, s, k, c: seg_fanin_bf(v, u, s, k, c,
+                                                    interpret=False))
+    text = fn.lower(tile, tile, tile, tile, scal).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_group_step_compiles_with_native_fanin(one_chip, monkeypatch):
+    """One whole PigPaxos N=25 scan step program with the Pallas fan-in
+    lowered natively (the path ``kernel="auto"`` takes on a TPU)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfgs = [vs.build_config("pigpaxos", 25, pig=PigConfig(n_groups=3,
+                                                           prc=1))]
+    grid = [(0, 24, s) for s in range(3)]
+    assert "tpu_custom_call" in _compile_step(one_chip, cfgs, grid, "pallas")
+
+
+def test_epaxos_step_compiles(one_chip):
+    cfgs = [vs.build_config("epaxos", 25, workload=WorkloadConfig(
+        key_dist="conflict", conflict_rate=0.1))]
+    grid = [(0, 24, s) for s in range(3)]
+    assert "while" in _compile_step(one_chip, cfgs, grid, "lax")

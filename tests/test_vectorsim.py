@@ -131,7 +131,10 @@ def test_seeds_differ():
                                  pig=PigConfig(n_groups=3, prc=1),
                                  clients=(20,), seeds=(0, 1),
                                  duration=0.15, warmup=0.05)
-    assert units[0]["throughput"] != units[1]["throughput"]
+    # two seeds can commit the same count in a short window (equal
+    # throughput); their latency distributions still differ
+    a, b = units[0], units[1]
+    assert (a["median_ms"], a["p99_ms"]) != (b["median_ms"], b["p99_ms"])
 
 
 # ------------------------------------------------ compilation contract
@@ -193,7 +196,7 @@ def test_sharded_exhausted_cells_retry():
 
 
 def test_sharded_grid_multidevice_subprocess():
-    """shard_map AND pmap over 4 forced host devices == single device,
+    """shard_map over 4 forced host devices == single device,
     bit for bit, chunked and unchunked (subprocess keeps pytest's own
     jax single-device)."""
     import os
