@@ -98,6 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec
 
 from .messages import HEADER_BYTES, CostModel
@@ -569,246 +570,258 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
 
     def step_fn(carry, i):
         ready, cpuF, cpuL, loadF, loadL, dt_ewma, t_prev = carry
-        k1, k2 = jax.random.split(jax.random.fold_in(key, i))
-        neg, cids = lax.top_k(-ready, B)
-        t0 = -neg                              # (B,) ascending issue times
-        active = t0 < stop
-        any_active = active[0]                 # actives are a prefix
+        with jax.named_scope("ingress"):
+            k1, k2 = jax.random.split(jax.random.fold_in(key, i))
+            neg, cids = lax.top_k(-ready, B)
+            t0 = -neg                              # (B,) ascending issue times
+            active = t0 < stop
+            any_active = active[0]                 # actives are a prefix
 
-        e = jax.random.exponential(k1, (B, 2 + 2 * G + 2 * F)) * jitter
-        e_cl = e[:, :2]
-        e_Lr = e[:, 2:2 + G]
-        e_rL = e[:, 2 + G:2 + 2 * G]
-        e_rp = e[:, 2 + 2 * G:2 + 2 * G + F]
-        e_pr = e[:, 2 + 2 * G + F:]
-        u_rel = jax.random.uniform(k2, (B, G))
+            e = jax.random.exponential(k1, (B, 2 + 2 * G + 2 * F)) * jitter
+            e_cl = e[:, :2]
+            e_Lr = e[:, 2:2 + G]
+            e_rL = e[:, 2 + G:2 + 2 * G]
+            e_rp = e[:, 2 + 2 * G:2 + 2 * G + F]
+            e_pr = e[:, 2 + 2 * G + F:]
+            u_rel = jax.random.uniform(k2, (B, G))
 
-        # leader ingress: exact FIFO over the burst (Lindley recursion with
-        # constant work T_l), seeded by the accumulator.  W_L — the queueing
-        # wait each request just experienced — doubles as the stationary
-        # estimate of the wait its own aggregates will see one RTT later.
-        aL = t0 + b_cl + e_cl[:, 0]
-        if faulty:
-            # a request arriving at a down leader waits out the window
-            # (the DES client's timeout retries land right after recovery)
-            aL = defer(aL + slowL, downL)
-        if read:
-            # leased reads serve at the leader only: service is ingest +
-            # reply, writes keep the full round's work.  The exclusive
-            # prefix sum Wc generalizes the constant-work kk_b * T_l chain
-            # (it reduces to it when every service equals T_l).
-            u_read = jax.random.uniform(jax.random.fold_in(k2, 1), (B,))
-            is_read = u_read < cell["read_ratio"]
-            w_serve = jnp.where(is_read, c_req + c_replycl, T_l)
-            Wc = jnp.cumsum(w_serve) - w_serve
-            start_b = jnp.maximum(lax.cummax(aL - Wc) + Wc, cpuL + Wc)
-            cpuL_next = jnp.maximum(
-                cpuL, jnp.where(active, start_b + w_serve, -jnp.inf).max())
-        else:
-            start_b = jnp.maximum(lax.cummax(aL - kk_b * T_l) + kk_b * T_l,
-                                  cpuL + kk_b * T_l)
-            cpuL_next = jnp.maximum(
-                cpuL, jnp.where(active, start_b + T_l, -jnp.inf).max())
-        W_L = start_b - aL
-        L1 = start_b + c_req
-        fan_done = L1[:, None] + (kk_r[None, :] + 1.0) * c_fanout
-        cpuL2 = L1 + ngf * c_fanout
+            # leader ingress: exact FIFO over the burst (Lindley recursion
+            # with constant work T_l), seeded by the accumulator.  W_L — the
+            # queueing wait each request just experienced — doubles as the
+            # stationary estimate of the wait its own aggregates will see one
+            # RTT later.
+            aL = t0 + b_cl + e_cl[:, 0]
+            if faulty:
+                # a request arriving at a down leader waits out the window
+                # (the DES client's timeout retries land right after recovery)
+                aL = defer(aL + slowL, downL)
+            if read:
+                # leased reads serve at the leader only: service is ingest +
+                # reply, writes keep the full round's work.  The exclusive
+                # prefix sum Wc generalizes the constant-work kk_b * T_l chain
+                # (it reduces to it when every service equals T_l).
+                u_read = jax.random.uniform(jax.random.fold_in(k2, 1), (B,))
+                is_read = u_read < cell["read_ratio"]
+                w_serve = jnp.where(is_read, c_req + c_replycl, T_l)
+                Wc = jnp.cumsum(w_serve) - w_serve
+                start_b = jnp.maximum(lax.cummax(aL - Wc) + Wc, cpuL + Wc)
+                cpuL_next = jnp.maximum(
+                    cpuL, jnp.where(active, start_b + w_serve, -jnp.inf).max())
+            else:
+                start_b = jnp.maximum(lax.cummax(aL - kk_b * T_l) + kk_b * T_l,
+                                      cpuL + kk_b * T_l)
+                cpuL_next = jnp.maximum(
+                    cpuL, jnp.where(active, start_b + T_l, -jnp.inf).max())
+            W_L = start_b - aL
+            L1 = start_b + c_req
+            fan_done = L1[:, None] + (kk_r[None, :] + 1.0) * c_fanout
+            cpuL2 = L1 + ngf * c_fanout
 
-        # rotating-relay choice.  Fault path: sample uniformly among the
-        # group members that are UP at the burst's pacing point (the DES
-        # leader gray-lists a dead relay after one timeout and avoids it, so
-        # steady-state relay duty falls on the live members) — reduces to
-        # the plain floor(u * size) draw when everyone is up.  Static relays
-        # are pinned to slot 0 even when down (the DES retries the same
-        # dead relay forever in that mode; the round defers identically).
-        if faulty:
-            tref = L1[0]
-            down0 = ((tref >= downF[:, :, 0])
-                     & (tref < downF[:, :, 1])).any(-1)   # (F,)
-            af = (valid & ~down0).astype(f32)
-            rank = seg_cumsum(af, seg_first[0], axis=0) - af  # rank among up
-            cnt = jnp.zeros(G, f32).at[grp].add(af)       # (G,) up members
-            k_sel = jnp.minimum(jnp.floor(u_rel * cnt[None, :]),
-                                jnp.maximum(cnt - 1.0, 0.0))   # (B, G)
-            k_slot = jnp.take_along_axis(k_sel, grp_b, axis=1)  # (B, F)
-            is_sel = (af > 0)[None, :] & (rank[None, :] == k_slot)
-            j_dyn = jnp.zeros((B, G), f32).at[:, grp].add(
-                jnp.where(is_sel, posf[None, :], 0.0))
-            j_rel = jnp.where(cell["static_relay"], 0,
-                              j_dyn.astype(jnp.int32))
-        else:
-            j_rel = jnp.where(cell["static_relay"], 0,
-                              jnp.floor(u_rel * szf).astype(jnp.int32))
-        j_rel = jnp.clip(j_rel, 0, jnp.maximum(sizes - 1, 0))
-        rel_idx = jnp.clip(gstart + j_rel, 0, F - 1)      # (B, G) flat slots
+        with jax.named_scope("relay_pick"):
+            # rotating-relay choice.  Fault path: sample uniformly among the
+            # group members that are UP at the burst's pacing point (the DES
+            # leader gray-lists a dead relay after one timeout and avoids it,
+            # so steady-state relay duty falls on the live members) — reduces
+            # to the plain floor(u * size) draw when everyone is up.  Static
+            # relays are pinned to slot 0 even when down (the DES retries the
+            # same dead relay forever in that mode; the round defers
+            # identically).
+            if faulty:
+                tref = L1[0]
+                down0 = ((tref >= downF[:, :, 0])
+                         & (tref < downF[:, :, 1])).any(-1)   # (F,)
+                af = (valid & ~down0).astype(f32)
+                # rank among the up members
+                rank = seg_cumsum(af, seg_first[0], axis=0) - af
+                cnt = jnp.zeros(G, f32).at[grp].add(af)       # (G,) up members
+                k_sel = jnp.minimum(jnp.floor(u_rel * cnt[None, :]),
+                                    jnp.maximum(cnt - 1.0, 0.0))   # (B, G)
+                k_slot = jnp.take_along_axis(k_sel, grp_b, axis=1)  # (B, F)
+                is_sel = (af > 0)[None, :] & (rank[None, :] == k_slot)
+                j_dyn = jnp.zeros((B, G), f32).at[:, grp].add(
+                    jnp.where(is_sel, posf[None, :], 0.0))
+                j_rel = jnp.where(cell["static_relay"], 0,
+                                  j_dyn.astype(jnp.int32))
+            else:
+                j_rel = jnp.where(cell["static_relay"], 0,
+                                  jnp.floor(u_rel * szf).astype(jnp.int32))
+            j_rel = jnp.clip(j_rel, 0, jnp.maximum(sizes - 1, 0))
+            rel_idx = jnp.clip(gstart + j_rel, 0, F - 1)  # (B, G) flat slots
 
-        # online rate estimate (EWMA of the L1 pacing interval) -> follower
-        # utilization rho and an M/D/1 stochastic-wait floor
-        n_act = jnp.maximum(active.sum().astype(f32), 1.0)
-        last_L1 = jnp.where(active, L1, -jnp.inf).max()
-        dt_ewma = jnp.where(any_active,
-                            0.95 * dt_ewma
-                            + 0.05 * (last_L1 - t_prev) / n_act, dt_ewma)
-        t_prev = jnp.where(any_active, last_L1, t_prev)
-        rho = jnp.clip(cell["w_follower"] / jnp.maximum(dt_ewma, 1e-9),
-                       0.0, 0.95)
-        md1 = rho * w_peer / (2.0 * (1.0 - rho))
+        with jax.named_scope("relay_fanout"):
+            # online rate estimate (EWMA of the L1 pacing interval) -> follower
+            # utilization rho and an M/D/1 stochastic-wait floor
+            n_act = jnp.maximum(active.sum().astype(f32), 1.0)
+            last_L1 = jnp.where(active, L1, -jnp.inf).max()
+            dt_ewma = jnp.where(any_active,
+                                0.95 * dt_ewma
+                                + 0.05 * (last_L1 - t_prev) / n_act, dt_ewma)
+            t_prev = jnp.where(any_active, last_L1, t_prev)
+            rho = jnp.clip(cell["w_follower"] / jnp.maximum(dt_ewma, 1e-9),
+                           0.0, 0.95)
+            md1 = rho * w_peer / (2.0 * (1.0 - rho))
 
-        # relay: receive the fanout, re-broadcast to its group peers.
-        # Follower CPUs are fluid work-backlog accumulators anchored at L1,
-        # the leader's pacing point (monotone over the scan): waits are the
-        # outstanding WORK at the node with a fluid drain to the arrival
-        # time plus the M/D/1 floor — never a wall-clock reservation.
-        # Anchoring at the (late, cross-round out-of-order) arrival times
-        # would let one round's pipeline latency masquerade as CPU backlog
-        # for the next round and cascade; anchoring at the client issue time
-        # t0 would let closed-loop reissue waves masquerade as backlog the
-        # leader's serialization actually paces out.
-        # LAN batches (reg_lat is 1x1 — a static shape) skip every region
-        # gather: all link bases collapse to one scalar
-        lan = reg_lat.shape[0] == 1
-        if lan:
-            b_Lr = b_rL = reg_lat[0, 0]
-            b_rp = b_pr = reg_lat[0, 0]
-        else:
-            reg_relay = regF[rel_idx]                     # (B, G)
-            b_Lr = reg_lat[leader_reg, reg_relay]
-            b_rL = reg_lat[reg_relay, leader_reg]
-            # per-direction bases: one-way matrices may be asymmetric
-            reg_relay_f = jnp.take_along_axis(reg_relay, grp_b, axis=1)
-            b_rp = reg_lat[reg_relay_f, regF[None, :]]    # (B, F) out
-            b_pr = reg_lat[regF[None, :], reg_relay_f]    # (B, F) back
-        arr_rel = fan_done + b_Lr + e_Lr
-        if faulty:
-            slow_rel = slowF[rel_idx]                     # (B, G)
-            arr_rel = defer(arr_rel + slowL + slow_rel, downF[rel_idx])
-        B_r = cpuF[rel_idx] - L1[:, None]
-        W_r = jnp.maximum(B_r + (rho - 1.0) * (arr_rel - L1[:, None]),
-                          0.0) + md1
-        h = arr_rel + W_r + c_fanout
-        is_relay = pos[None, :] == j_rel[:, grp]          # (B, F)
-        peer_mask = valid[None, :] & ~is_relay
-        order = (pos[None, :] - (pos[None, :] > j_rel[:, grp])).astype(f32)
-        send_done = jnp.take_along_axis(h, grp_b, axis=1) \
-            + (order + 1.0) * c_rel
-        arr_p = send_done + b_rp + e_rp
-        if faulty:
-            # relay-out + peer-in slow extras; a down peer serves the
-            # relayed message after it recovers (its vote arrives late and
-            # simply sorts past the flush threshold if others cover it)
-            slow_rel_f = jnp.take_along_axis(slow_rel, grp_b, axis=1)
-            arr_p = defer(arr_p + slow_rel_f + slowF[None, :], downF)
-        W_p = jnp.maximum(cpuF[None, :] - L1[:, None]
-                          + (rho - 1.0) * (arr_p - L1[:, None]), 0.0) + md1
-        doneP = arr_p + W_p + c_rel + c_repl
-        arr_back = doneP + b_pr + e_pr
-        if faulty:
-            # the returning reply queues at the relay once IT is back up
-            win_rel_f = jnp.take_along_axis(
-                downF[rel_idx], grp_b[..., None, None], axis=1)  # (B,F,W,2)
-            arr_back = defer(arr_back + slow_rel_f + slowF[None, :],
-                             win_rel_f)
+            # relay: receive the fanout, re-broadcast to its group peers.
+            # Follower CPUs are fluid work-backlog accumulators anchored at L1,
+            # the leader's pacing point (monotone over the scan): waits are the
+            # outstanding WORK at the node with a fluid drain to the arrival
+            # time plus the M/D/1 floor — never a wall-clock reservation.
+            # Anchoring at the (late, cross-round out-of-order) arrival times
+            # would let one round's pipeline latency masquerade as CPU backlog
+            # for the next round and cascade; anchoring at the client issue
+            # time t0 would let closed-loop reissue waves masquerade as
+            # backlog the leader's serialization actually paces out.
+            # LAN batches (reg_lat is 1x1 — a static shape) skip every region
+            # gather: all link bases collapse to one scalar
+            lan = reg_lat.shape[0] == 1
+            if lan:
+                b_Lr = b_rL = reg_lat[0, 0]
+                b_rp = b_pr = reg_lat[0, 0]
+            else:
+                reg_relay = regF[rel_idx]                     # (B, G)
+                b_Lr = reg_lat[leader_reg, reg_relay]
+                b_rL = reg_lat[reg_relay, leader_reg]
+                # per-direction bases: one-way matrices may be asymmetric
+                reg_relay_f = jnp.take_along_axis(reg_relay, grp_b, axis=1)
+                b_rp = reg_lat[reg_relay_f, regF[None, :]]    # (B, F) out
+                b_pr = reg_lat[regF[None, :], reg_relay_f]    # (B, F) back
+            arr_rel = fan_done + b_Lr + e_Lr
+            if faulty:
+                slow_rel = slowF[rel_idx]                     # (B, G)
+                arr_rel = defer(arr_rel + slowL + slow_rel, downF[rel_idx])
+            B_r = cpuF[rel_idx] - L1[:, None]
+            W_r = jnp.maximum(B_r + (rho - 1.0) * (arr_rel - L1[:, None]),
+                              0.0) + md1
+            h = arr_rel + W_r + c_fanout
+            is_relay = pos[None, :] == j_rel[:, grp]          # (B, F)
+            peer_mask = valid[None, :] & ~is_relay
+            order = (pos[None, :] - (pos[None, :] > j_rel[:, grp])).astype(f32)
+            send_done = jnp.take_along_axis(h, grp_b, axis=1) \
+                + (order + 1.0) * c_rel
+            arr_p = send_done + b_rp + e_rp
+            if faulty:
+                # relay-out + peer-in slow extras; a down peer serves the
+                # relayed message after it recovers (its vote arrives late and
+                # simply sorts past the flush threshold if others cover it)
+                slow_rel_f = jnp.take_along_axis(slow_rel, grp_b, axis=1)
+                arr_p = defer(arr_p + slow_rel_f + slowF[None, :], downF)
+            W_p = jnp.maximum(cpuF[None, :] - L1[:, None]
+                              + (rho - 1.0) * (arr_p - L1[:, None]), 0.0) + md1
+            doneP = arr_p + W_p + c_rel + c_repl
+            arr_back = doneP + b_pr + e_pr
+            if faulty:
+                # the returning reply queues at the relay once IT is back up
+                win_rel_f = jnp.take_along_axis(
+                    downF[rel_idx], grp_b[..., None, None],
+                    axis=1)                                   # (B,F,W,2)
+                arr_back = defer(arr_back + slow_rel_f + slowF[None, :],
+                                 win_rel_f)
 
-        # relay FIFO over its reply fan-in: k-th completion via key-sorted
-        # arrivals + segmented cumulative max (done_k = max(arr_k,
-        # done_{k-1}) + c); each returning reply queues behind the relay's
-        # fluid-drained backlog and this round's own sends (relay_free0).
-        # The lexicographic (group, arrival) sort keeps each group's segment
-        # block in place with arrivals ascending, so the value at flat slot
-        # f is group grp[f]'s pos[f]-th reply.
-        relay_free0 = h + npeers.astype(f32)[None, :] * c_rel
-        kg = jnp.maximum(thresh - 2, 0)
-        if kernel == "pallas":
-            # rank-counting Pallas kernel: emits each slot's capped segment
-            # max directly (the thresh-2 order statistic), no sort needed
-            from ..kernels import ops as _kops
-            m = _kops.seg_fanin(
-                jnp.where(peer_mask, arr_back, jnp.inf),
-                jnp.take_along_axis(B_r, grp_b, axis=1),
-                grp, kg[grp], rho - 1.0, md1, c_repl, L1)
-            mg = jnp.take_along_axis(
-                m, jnp.broadcast_to(jnp.clip(gstart, 0, F - 1), (B, G)),
-                axis=1)
-            done_g = (kg.astype(f32)[None, :] + 1.0) * c_repl \
-                + jnp.maximum(relay_free0, mg)
-        else:
-            _, arr_s = lax.sort(
-                (grp_b, jnp.where(peer_mask, arr_back, jnp.inf)), num_keys=2)
-            w_fan = jnp.maximum(
-                jnp.take_along_axis(B_r, grp_b, axis=1)
-                + (rho - 1.0) * (arr_s - L1[:, None]), 0.0) + md1
-            pref = seg_cummax(arr_s + w_fan - posf[None, :] * c_repl,
-                              seg_first, axis=1)
-            done_k = (posf[None, :] + 1.0) * c_repl + jnp.maximum(
-                jnp.take_along_axis(relay_free0, grp_b, axis=1), pref)
-            t_idx = jnp.clip(gstart + thresh - 2, 0, F - 1)
-            done_g = jnp.take_along_axis(
-                done_k, jnp.broadcast_to(t_idx, (B, G)), axis=1)
-        flush = jnp.where((thresh >= 2)[None, :], done_g, relay_free0)
-        agg_sent = flush + c_agg
+        with jax.named_scope("relay_acks"):
+            # relay FIFO over its reply fan-in: k-th completion via
+            # key-sorted arrivals + segmented cumulative max (done_k =
+            # max(arr_k, done_{k-1}) + c); each returning reply queues behind
+            # the relay's fluid-drained backlog and this round's own sends
+            # (relay_free0).  The lexicographic (group, arrival) sort keeps
+            # each group's segment block in place with arrivals ascending, so
+            # the value at flat slot f is group grp[f]'s pos[f]-th reply.
+            relay_free0 = h + npeers.astype(f32)[None, :] * c_rel
+            kg = jnp.maximum(thresh - 2, 0)
+            if kernel == "pallas":
+                # rank-counting Pallas kernel: emits each slot's capped segment
+                # max directly (the thresh-2 order statistic), no sort needed
+                from ..kernels import ops as _kops
+                m = _kops.seg_fanin(
+                    jnp.where(peer_mask, arr_back, jnp.inf),
+                    jnp.take_along_axis(B_r, grp_b, axis=1),
+                    grp, kg[grp], rho - 1.0, md1, c_repl, L1)
+                mg = jnp.take_along_axis(
+                    m, jnp.broadcast_to(jnp.clip(gstart, 0, F - 1), (B, G)),
+                    axis=1)
+                done_g = (kg.astype(f32)[None, :] + 1.0) * c_repl \
+                    + jnp.maximum(relay_free0, mg)
+            else:
+                _, arr_s = lax.sort(
+                    (grp_b, jnp.where(peer_mask, arr_back, jnp.inf)),
+                    num_keys=2)
+                w_fan = jnp.maximum(
+                    jnp.take_along_axis(B_r, grp_b, axis=1)
+                    + (rho - 1.0) * (arr_s - L1[:, None]), 0.0) + md1
+                pref = seg_cummax(arr_s + w_fan - posf[None, :] * c_repl,
+                                  seg_first, axis=1)
+                done_k = (posf[None, :] + 1.0) * c_repl + jnp.maximum(
+                    jnp.take_along_axis(relay_free0, grp_b, axis=1), pref)
+                t_idx = jnp.clip(gstart + thresh - 2, 0, F - 1)
+                done_g = jnp.take_along_axis(
+                    done_k, jnp.broadcast_to(t_idx, (B, G)), axis=1)
+            flush = jnp.where((thresh >= 2)[None, :], done_g, relay_free0)
+            agg_sent = flush + c_agg
 
-        # leader FIFO over aggregates; commit at the quorum-completing one
-        agg_in = agg_sent + b_rL + e_rL
-        if faulty:
-            agg_in = defer(agg_in + slow_rel + slowL, downL)
-        arr_agg = jnp.where(grp_mask[None, :], agg_in, jnp.inf)
-        acks_b = jnp.broadcast_to(acks, (B, G))
-        arr_as, acks_s = lax.sort((arr_agg, acks_b), num_keys=1)
-        cum = jnp.cumsum(acks_s, axis=1)
-        got = 1.0 + cum >= majf
-        kstar = jnp.argmax(got, axis=1)
-        prefL = lax.cummax(arr_as + W_L[:, None] - kk_r[None, :] * c_agg,
-                           axis=1)
-        doneL = (kk_r[None, :] + 1.0) * c_agg \
-            + jnp.maximum(cpuL2[:, None], prefL)
-        commit_done = jnp.where(
-            jnp.any(got, axis=1),
-            jnp.take_along_axis(doneL, kstar[:, None], axis=1)[:, 0],
-            jnp.inf)
-        reply_done = commit_done + c_replycl
-        t_fin = reply_done + reg_lat[leader_reg, 0] + e_cl[:, 1]
-        if faulty:
-            t_fin = t_fin + slowL
-        if read:
-            # leased reads never enter the log: the reply leaves the leader
-            # at service completion, and commit_done = inf keeps them out
-            # of `committed` and every commit-windowed load
-            read_fin = (start_b + w_serve + reg_lat[leader_reg, 0]
-                        + e_cl[:, 1])
-            commit_done = jnp.where(is_read, jnp.inf, commit_done)
-            t_fin = jnp.where(is_read, read_fin, t_fin)
+        with jax.named_scope("commit"):
+            # leader FIFO over aggregates; commit at the quorum-completing one
+            agg_in = agg_sent + b_rL + e_rL
+            if faulty:
+                agg_in = defer(agg_in + slow_rel + slowL, downL)
+            arr_agg = jnp.where(grp_mask[None, :], agg_in, jnp.inf)
+            acks_b = jnp.broadcast_to(acks, (B, G))
+            arr_as, acks_s = lax.sort((arr_agg, acks_b), num_keys=1)
+            cum = jnp.cumsum(acks_s, axis=1)
+            got = 1.0 + cum >= majf
+            kstar = jnp.argmax(got, axis=1)
+            prefL = lax.cummax(arr_as + W_L[:, None] - kk_r[None, :] * c_agg,
+                               axis=1)
+            doneL = (kk_r[None, :] + 1.0) * c_agg \
+                + jnp.maximum(cpuL2[:, None], prefL)
+            commit_done = jnp.where(
+                jnp.any(got, axis=1),
+                jnp.take_along_axis(doneL, kstar[:, None], axis=1)[:, 0],
+                jnp.inf)
+            reply_done = commit_done + c_replycl
+            t_fin = reply_done + reg_lat[leader_reg, 0] + e_cl[:, 1]
+            if faulty:
+                t_fin = t_fin + slowL
+            if read:
+                # leased reads never enter the log: the reply leaves the leader
+                # at service completion, and commit_done = inf keeps them out
+                # of `committed` and every commit-windowed load
+                read_fin = (start_b + w_serve + reg_lat[leader_reg, 0]
+                            + e_cl[:, 1])
+                commit_done = jnp.where(is_read, jnp.inf, commit_done)
+                t_fin = jnp.where(is_read, read_fin, t_fin)
 
-        # state updates: follower backlogs grow by the burst's per-node WORK
-        # from the anchor (the first active request's pacing point — every
-        # round touches every follower, so that is the first toucher)
-        act_b = ((active & ~is_read) if read else active)[:, None]
-        add_w = (jnp.where(act_b & peer_mask, w_peer, 0.0).sum(axis=0)
-                 .at[jnp.where(act_b & grp_mask[None, :], rel_idx, F)]
-                 .add(jnp.broadcast_to(relay_work, (B, G)), mode="drop"))
-        anchored = jnp.maximum(cpuF, jnp.where(any_active, L1[0], 0.0))
-        cpuF = jnp.where(any_active, anchored + add_w, cpuF)
-        cpuL = jnp.where(any_active, cpuL_next, cpuL)
-        ready = ready.at[cids].set(jnp.where(active, t_fin, jnp.inf))
+        with jax.named_scope("state"):
+            # state updates: follower backlogs grow by the burst's per-node
+            # WORK from the anchor (the first active request's pacing point —
+            # every round touches every follower, so that is the first
+            # toucher)
+            act_b = ((active & ~is_read) if read else active)[:, None]
+            add_w = (jnp.where(act_b & peer_mask, w_peer, 0.0).sum(axis=0)
+                     .at[jnp.where(act_b & grp_mask[None, :], rel_idx, F)]
+                     .add(jnp.broadcast_to(relay_work, (B, G)), mode="drop"))
+            anchored = jnp.maximum(cpuF, jnp.where(any_active, L1[0], 0.0))
+            cpuF = jnp.where(any_active, anchored + add_w, cpuF)
+            cpuL = jnp.where(any_active, cpuL_next, cpuL)
+            ready = ready.at[cids].set(jnp.where(active, t_fin, jnp.inf))
 
-        # per-node message loads, accumulated over the measurement window
-        in_win = active & (commit_done >= warmup) & (commit_done
-                                                     <= stop + _DRAIN_S)
-        win_b = in_win[:, None]
-        loadF = loadF + (jnp.where(win_b & peer_mask, 2.0, 0.0).sum(axis=0)
-                         .at[jnp.where(win_b & grp_mask[None, :],
-                                       rel_idx, F)]
-                         .add(jnp.broadcast_to(2.0 * szf, (B, G)),
-                              mode="drop"))
-        loadL = loadL + jnp.where(in_win, 2.0 * ngf + 2.0, 0.0).sum()
+            # per-node message loads, accumulated over the measurement window
+            in_win = active & (commit_done >= warmup) & (commit_done
+                                                         <= stop + _DRAIN_S)
+            win_b = in_win[:, None]
+            loadF = loadF + (jnp.where(win_b & peer_mask, 2.0, 0.0).sum(axis=0)
+                             .at[jnp.where(win_b & grp_mask[None, :],
+                                           rel_idx, F)]
+                             .add(jnp.broadcast_to(2.0 * szf, (B, G)),
+                                  mode="drop"))
+            loadL = loadL + jnp.where(in_win, 2.0 * ngf + 2.0, 0.0).sum()
 
-        ys = (t_fin - t0, t_fin, commit_done, active)
-        if obs:
-            # leader-backlog observation: the wait the step's first popped
-            # request just experienced at the leader FIFO (= backlog in
-            # seconds at its arrival instant), stamped with that arrival
-            ys = ys + (jnp.where(any_active, aL[0], jnp.inf), W_L[0])
-        if read:
-            ys = ys + (is_read,)
+            ys = (t_fin - t0, t_fin, commit_done, active)
+            if obs:
+                # leader-backlog observation: the wait the step's first popped
+                # request just experienced at the leader FIFO (= backlog in
+                # seconds at its arrival instant), stamped with that arrival
+                ys = ys + (jnp.where(any_active, aL[0], jnp.inf), W_L[0])
+            if read:
+                ys = ys + (is_read,)
         return ((ready, cpuF, cpuL, loadF, loadL, dt_ewma, t_prev),
                 ys)
 
@@ -817,39 +830,41 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
               jnp.float32(1.0), jnp.float32(0.0))
     (ready, _, _, loadF, loadL, _, _), ys = \
         lax.scan(step_fn, carry0, jnp.arange(steps))
-    lat, t_fin, commit_t, active = ys[:4]
-    out = _summarize(lat.reshape(-1), t_fin.reshape(-1),
-                     commit_t.reshape(-1), active.reshape(-1), ready,
-                     loadF.sum(), loadL, cell, nb=nb)
-    if obs:
-        t_obs, qlag = ys[4], ys[5]
-        ok = jnp.isfinite(t_obs) & (t_obs <= stop + _DRAIN_S)
-        tb = jnp.clip(jnp.where(ok, jnp.floor(t_obs / _TL_BUCKET), 0.0)
-                      .astype(jnp.int32), 0, nb - 1)
-        w = ok.astype(f32)
-        qsum = jnp.zeros(nb, f32).at[tb].add(qlag * w)
-        qn = jnp.zeros(nb, f32).at[tb].add(w)
-        out["leader_backlog_s"] = jnp.where(qn > 0, qsum / jnp.maximum(qn, 1.0),
-                                            0.0)
-        out["leader_backlog_n"] = qn.astype(jnp.int32)
-    if read:
-        # read/write latency split over the same measurement window the
-        # headline latencies use (DES counterpart: Cluster.read_write_split)
-        isr = ys[-1].reshape(-1)
-        latf, tf = lat.reshape(-1), t_fin.reshape(-1)
-        in_lat = active.reshape(-1) & (tf >= cell["warmup"]) \
-            & (tf <= cell["stop"])
-        rm, wm = in_lat & isr, in_lat & ~isr
-        rn, wn = rm.sum(), wm.sum()
-        out["read_count"], out["write_count"] = rn, wn
-        out["read_mean_s"] = jnp.where(
-            rn > 0, jnp.where(rm, latf, 0.0).sum()
-            / jnp.maximum(rn.astype(f32), 1.0), jnp.nan)
-        out["write_mean_s"] = jnp.where(
-            wn > 0, jnp.where(wm, latf, 0.0).sum()
-            / jnp.maximum(wn.astype(f32), 1.0), jnp.nan)
-        out["read_p99_s"] = _pct(jnp.sort(jnp.where(rm, latf, jnp.inf)),
-                                 rn, 0.99)
+    with jax.named_scope("summary"):
+        lat, t_fin, commit_t, active = ys[:4]
+        out = _summarize(lat.reshape(-1), t_fin.reshape(-1),
+                         commit_t.reshape(-1), active.reshape(-1), ready,
+                         loadF.sum(), loadL, cell, nb=nb)
+        if obs:
+            t_obs, qlag = ys[4], ys[5]
+            ok = jnp.isfinite(t_obs) & (t_obs <= stop + _DRAIN_S)
+            tb = jnp.clip(jnp.where(ok, jnp.floor(t_obs / _TL_BUCKET), 0.0)
+                          .astype(jnp.int32), 0, nb - 1)
+            w = ok.astype(f32)
+            qsum = jnp.zeros(nb, f32).at[tb].add(qlag * w)
+            qn = jnp.zeros(nb, f32).at[tb].add(w)
+            out["leader_backlog_s"] = jnp.where(
+                qn > 0, qsum / jnp.maximum(qn, 1.0), 0.0)
+            out["leader_backlog_n"] = qn.astype(jnp.int32)
+        if read:
+            # read/write latency split over the same measurement window the
+            # headline latencies use (DES counterpart:
+            # Cluster.read_write_split)
+            isr = ys[-1].reshape(-1)
+            latf, tf = lat.reshape(-1), t_fin.reshape(-1)
+            in_lat = active.reshape(-1) & (tf >= cell["warmup"]) \
+                & (tf <= cell["stop"])
+            rm, wm = in_lat & isr, in_lat & ~isr
+            rn, wn = rm.sum(), wm.sum()
+            out["read_count"], out["write_count"] = rn, wn
+            out["read_mean_s"] = jnp.where(
+                rn > 0, jnp.where(rm, latf, 0.0).sum()
+                / jnp.maximum(rn.astype(f32), 1.0), jnp.nan)
+            out["write_mean_s"] = jnp.where(
+                wn > 0, jnp.where(wm, latf, 0.0).sum()
+                / jnp.maximum(wn.astype(f32), 1.0), jnp.nan)
+            out["read_p99_s"] = _pct(jnp.sort(jnp.where(rm, latf, jnp.inf)),
+                                     rn, 0.99)
     return out
 
 
@@ -894,110 +909,120 @@ def _epaxos_cell(cell, steps: int, kmax: int, nb: int = 0):
 
     def step_fn(carry, i):
         ready, cpu, load, race, depk = carry
-        ks = jax.random.split(jax.random.fold_in(key, i), 5)
-        cid = jnp.argmin(ready)
-        t0 = ready[cid]
-        active = t0 < stop
+        with jax.named_scope("keys"):
+            ks = jax.random.split(jax.random.fold_in(key, i), 5)
+            cid = jnp.argmin(ready)
+            t0 = ready[cid]
+            active = t0 < stop
 
-        coord = jax.random.randint(ks[0], (), 0, n)
-        e_cl = jax.random.exponential(ks[1], (2,)) * jitter
-        e_out = jax.random.exponential(ks[2], (n,)) * jitter
-        e_back = jax.random.exponential(ks[3], (n,)) * jitter
-        u_key = jax.random.uniform(ks[4], ())
+            coord = jax.random.randint(ks[0], (), 0, n)
+            e_cl = jax.random.exponential(ks[1], (2,)) * jitter
+            e_out = jax.random.exponential(ks[2], (n,)) * jitter
+            e_back = jax.random.exponential(ks[3], (n,)) * jitter
+            u_key = jax.random.uniform(ks[4], ())
 
-        # per-request key draw from the workload's distribution
-        k_uni = jnp.floor(u_key * nkeysf).astype(jnp.int32)
-        k_zipf = jnp.searchsorted(cell["key_cdf"], u_key,
-                                  side="right").astype(jnp.int32)
-        k_conf = jnp.where(
-            u_key < crate, 0,
-            1 + jnp.floor((u_key - crate) / jnp.maximum(1.0 - crate, 1e-9)
-                          * (nkeysf - 1.0)).astype(jnp.int32))
-        k = jnp.where(key_mode == 1, k_zipf,
-                      jnp.where(key_mode == 2, k_conf, k_uni))
-        k = jnp.clip(k, 0, cell["n_keys"] - 1)
+            # per-request key draw from the workload's distribution
+            k_uni = jnp.floor(u_key * nkeysf).astype(jnp.int32)
+            k_zipf = jnp.searchsorted(cell["key_cdf"], u_key,
+                                      side="right").astype(jnp.int32)
+            k_conf = jnp.where(
+                u_key < crate, 0,
+                1 + jnp.floor((u_key - crate) / jnp.maximum(1.0 - crate, 1e-9)
+                              * (nkeysf - 1.0)).astype(jnp.int32))
+            k = jnp.where(key_mode == 1, k_zipf,
+                          jnp.where(key_mode == 2, k_conf, k_uni))
+            k = jnp.clip(k, 0, cell["n_keys"] - 1)
 
-        coord_reg = reg_nodes[coord]
-        b_cl = reg_lat[0, coord_reg]          # clients live in region 0
-        b_cp = reg_lat[coord_reg, reg_nodes]  # coord -> peer bases (n,)
-        b_pc = reg_lat[reg_nodes, coord_reg]  # peer -> coord (asymmetric ok)
+        with jax.named_scope("preaccept"):
+            coord_reg = reg_nodes[coord]
+            b_cl = reg_lat[0, coord_reg]          # clients live in region 0
+            b_cp = reg_lat[coord_reg, reg_nodes]  # coord -> peer bases (n,)
+            # peer -> coord (asymmetric ok)
+            b_pc = reg_lat[reg_nodes, coord_reg]
 
-        # every node's CPU is a fluid work-backlog anchored at t0 (see the
-        # group kernel): the command-leader role rotates per request, so
-        # wall-clock anchoring would cascade across requests
-        aC = t0 + b_cl + e_cl[0]
-        W_C = jnp.maximum(cpu[coord] - t0, 0.0)
-        L1 = aC + W_C + c_req
-        is_peer = ids != coord
-        order = (ids - (ids > coord)).astype(f32)
-        pa_done = L1 + (order + 1.0) * c_pa
-        cpuC2 = L1 + (n - 1) * c_pa
+            # every node's CPU is a fluid work-backlog anchored at t0 (see the
+            # group kernel): the command-leader role rotates per request, so
+            # wall-clock anchoring would cascade across requests
+            aC = t0 + b_cl + e_cl[0]
+            W_C = jnp.maximum(cpu[coord] - t0, 0.0)
+            L1 = aC + W_C + c_req
+            is_peer = ids != coord
+            order = (ids - (ids > coord)).astype(f32)
+            pa_done = L1 + (order + 1.0) * c_pa
+            cpuC2 = L1 + (n - 1) * c_pa
 
-        arr_p = pa_done + b_cp + e_out
-        W_p = jnp.maximum(cpu - t0, 0.0)
-        doneP = arr_p + W_p + c_pa + c_par
-        arr_back = jnp.where(is_peer, doneP + b_pc + e_back, jnp.inf)
+            arr_p = pa_done + b_cp + e_out
+            W_p = jnp.maximum(cpu - t0, 0.0)
+            doneP = arr_p + W_p + c_pa + c_par
+            arr_back = jnp.where(is_peer, doneP + b_pc + e_back, jnp.inf)
 
-        # reply fan-in: the coordinator's backlog partially drains over the
-        # round trip (it keeps serving while the round is in flight), so the
-        # wait each reply sees decays from W_C with the elapsed time — the
-        # 0.5 net-drain rate is calibrated against the fast DES (the node
-        # also ingests new work while draining, see tests/test_vectorsim.py)
-        arr_s = jnp.sort(arr_back)
-        W_fan = jnp.maximum(W_C - 0.5 * (arr_s - L1), 0.0)
-        pref = lax.cummax(arr_s + W_fan - kk * c_par)
-        done_k = (kk + 1.0) * c_par + jnp.maximum(cpuC2, pref)
-        # fast-path commit after fq-1 peer replies (the leader votes itself)
-        fast_commit = done_k[jnp.clip(fq - 2, 0, n - 1)]
+            # reply fan-in: the coordinator's backlog partially drains over
+            # the round trip (it keeps serving while the round is in flight),
+            # so the wait each reply sees decays from W_C with the elapsed
+            # time — the 0.5 net-drain rate is calibrated against the fast
+            # DES (the node also ingests new work while draining, see
+            # tests/test_vectorsim.py)
+            arr_s = jnp.sort(arr_back)
+            W_fan = jnp.maximum(W_C - 0.5 * (arr_s - L1), 0.0)
+            pref = lax.cummax(arr_s + W_fan - kk * c_par)
+            done_k = (kk + 1.0) * c_par + jnp.maximum(cpuC2, pref)
+            # fast-path commit after fq-1 peer replies (the leader votes
+            # itself)
+            fast_commit = done_k[jnp.clip(fq - 2, 0, n - 1)]
 
-        # conflict draw: the previous same-key instance's PreAccept round is
-        # still propagating when we fan out -> peers report divergent deps
-        # and the coordinator falls back to the Paxos-accept slow path
-        slow = active & (L1 < race[k])
-        acc_done = fast_commit + (order + 1.0) * c_acc
-        cpuC3 = fast_commit + (n - 1) * c_acc
-        arr_p2 = acc_done + b_cp + e_out
-        doneP2 = arr_p2 + W_p + c_acc + c_accr
-        arr_back2 = jnp.where(is_peer, doneP2 + b_pc + e_back, jnp.inf)
-        arr_s2 = jnp.sort(arr_back2)
-        W_fan2 = jnp.maximum(W_C - 0.5 * (arr_s2 - L1), 0.0)
-        pref2 = lax.cummax(arr_s2 + W_fan2 - kk * c_accr)
-        done_k2 = (kk + 1.0) * c_accr + jnp.maximum(cpuC3, pref2)
-        slow_commit = done_k2[jnp.clip(maj - 2, 0, n - 1)]
-        commit_done = jnp.where(slow, slow_commit, fast_commit)
+        with jax.named_scope("conflict"):
+            # conflict draw: the previous same-key instance's PreAccept round
+            # is still propagating when we fan out -> peers report divergent
+            # deps and the coordinator falls back to the Paxos-accept slow
+            # path
+            slow = active & (L1 < race[k])
+            acc_done = fast_commit + (order + 1.0) * c_acc
+            cpuC3 = fast_commit + (n - 1) * c_acc
+            arr_p2 = acc_done + b_cp + e_out
+            doneP2 = arr_p2 + W_p + c_acc + c_accr
+            arr_back2 = jnp.where(is_peer, doneP2 + b_pc + e_back, jnp.inf)
+            arr_s2 = jnp.sort(arr_back2)
+            W_fan2 = jnp.maximum(W_C - 0.5 * (arr_s2 - L1), 0.0)
+            pref2 = lax.cummax(arr_s2 + W_fan2 - kk * c_accr)
+            done_k2 = (kk + 1.0) * c_accr + jnp.maximum(cpuC3, pref2)
+            slow_commit = done_k2[jnp.clip(maj - 2, 0, n - 1)]
+            commit_done = jnp.where(slow, slow_commit, fast_commit)
 
-        # dependency-order execution: a same-key successor cannot execute
-        # (and answer its client) before the predecessor's commit is known
-        # at its coordinator
-        exec_done = jnp.maximum(commit_done + (n - 1) * c_com, depk[k])
-        reply_done = exec_done + c_replycl
-        t_fin = reply_done + reg_lat[coord_reg, 0] + e_cl[1]
+        with jax.named_scope("exec_gate"):
+            # dependency-order execution: a same-key successor cannot execute
+            # (and answer its client) before the predecessor's commit is known
+            # at its coordinator
+            exec_done = jnp.maximum(commit_done + (n - 1) * c_com, depk[k])
+            reply_done = exec_done + c_replycl
+            t_fin = reply_done + reg_lat[coord_reg, 0] + e_cl[1]
 
-        slowf = slow.astype(f32)
-        anchored = jnp.maximum(cpu, t0)
-        coord_work = (c_req + (n - 1) * (c_pa + c_par + c_com) + c_replycl
-                      + slowf * (n - 1) * (c_acc + c_accr))
-        new_cpu = jnp.where(is_peer,
-                            anchored + c_pa + c_par + c_com
-                            + slowf * (c_acc + c_accr), cpu)
-        new_cpu = new_cpu.at[coord].set(anchored[coord] + coord_work)
-        cpu = jnp.where(active, new_cpu, cpu)
-        ready = ready.at[cid].set(jnp.where(active, t_fin, jnp.inf))
+        with jax.named_scope("state"):
+            slowf = slow.astype(f32)
+            anchored = jnp.maximum(cpu, t0)
+            coord_work = (c_req + (n - 1) * (c_pa + c_par + c_com) + c_replycl
+                          + slowf * (n - 1) * (c_acc + c_accr))
+            new_cpu = jnp.where(is_peer,
+                                anchored + c_pa + c_par + c_com
+                                + slowf * (c_acc + c_accr), cpu)
+            new_cpu = new_cpu.at[coord].set(anchored[coord] + coord_work)
+            cpu = jnp.where(active, new_cpu, cpu)
+            ready = ready.at[cid].set(jnp.where(active, t_fin, jnp.inf))
 
-        # conflict-tracking state: when every peer has processed this
-        # request's PreAccept (race), and when its commit is known
-        # everywhere (depk — ECommit broadcast plus a one-way hop)
-        race_new = jnp.where(is_peer, arr_p + W_p + c_pa, -jnp.inf).max()
-        b_prop = jnp.where(is_peer, b_cp, 0.0).sum() / jnp.maximum(n - 1, 1)
-        dep_new = commit_done + (n - 1) * c_com + b_prop + jitter
-        race = race.at[k].set(jnp.where(active, race_new, race[k]))
-        depk = depk.at[k].set(jnp.where(active, dep_new, depk[k]))
+            # conflict-tracking state: when every peer has processed this
+            # request's PreAccept (race), and when its commit is known
+            # everywhere (depk — ECommit broadcast plus a one-way hop)
+            race_new = jnp.where(is_peer, arr_p + W_p + c_pa, -jnp.inf).max()
+            b_prop = (jnp.where(is_peer, b_cp, 0.0).sum()
+                      / jnp.maximum(n - 1, 1))
+            dep_new = commit_done + (n - 1) * c_com + b_prop + jitter
+            race = race.at[k].set(jnp.where(active, race_new, race[k]))
+            depk = depk.at[k].set(jnp.where(active, dep_new, depk[k]))
 
-        in_win = active & (commit_done >= warmup) & (commit_done
-                                                     <= stop + _DRAIN_S)
-        add = jnp.where(is_peer, 3.0 + 2.0 * slowf,
-                        (3.0 * n - 1.0) + 2.0 * (n - 1) * slowf)
-        load = load + jnp.where(in_win, 1.0, 0.0) * add
+            in_win = active & (commit_done >= warmup) & (commit_done
+                                                         <= stop + _DRAIN_S)
+            add = jnp.where(is_peer, 3.0 + 2.0 * slowf,
+                            (3.0 * n - 1.0) + 2.0 * (n - 1) * slowf)
+            load = load + jnp.where(in_win, 1.0, 0.0) * add
 
         return ((ready, cpu, load, race, depk),
                 (t_fin - t0, t_fin, commit_done, active))
@@ -1006,9 +1031,10 @@ def _epaxos_cell(cell, steps: int, kmax: int, nb: int = 0):
               jnp.zeros(nk, f32), jnp.zeros(nk, f32))
     (ready, _, load, _, _), (lat, t_fin, commit_t, active) = lax.scan(
         step_fn, carry0, jnp.arange(steps))
-    # symmetric protocol: report node 0 as "leader", the rest as followers
-    return _summarize(lat, t_fin, commit_t, active, ready,
-                      load[1:].sum(), load[0], cell, nb=nb)
+    with jax.named_scope("summary"):
+        # symmetric protocol: report node 0 as "leader", the rest as followers
+        return _summarize(lat, t_fin, commit_t, active, ready,
+                          load[1:].sum(), load[0], cell, nb=nb)
 
 
 # ================================================================== batching
@@ -1029,12 +1055,14 @@ def _cells_fn(batch, steps: int, kmax: int, kind: str, breq: int,
               faulty: bool = False, nb: int = 0, kernel: str = "lax",
               obs: bool = False, read: bool = False):
     """The unjitted whole-batch computation (vmap over cells); shared by
-    the single-device jit below and the sharded per-device bodies."""
-    if kind == "group":
-        return jax.vmap(lambda c: _group_cell(c, steps, kmax, breq,
-                                              faulty, nb, kernel,
-                                              obs, read))(batch)
-    return jax.vmap(lambda c: _epaxos_cell(c, steps, kmax, nb))(batch)
+    the single-device jit below and the sharded per-device bodies.  Runs
+    only while JAX traces it, so its span marks a retrace."""
+    with TraceAnnotation("vectorsim.trace"):
+        if kind == "group":
+            return jax.vmap(lambda c: _group_cell(c, steps, kmax, breq,
+                                                  faulty, nb, kernel,
+                                                  obs, read))(batch)
+        return jax.vmap(lambda c: _epaxos_cell(c, steps, kmax, nb))(batch)
 
 
 @functools.partial(jax.jit, static_argnames=("steps", "kmax", "kind",
@@ -1080,120 +1108,124 @@ def _stack_cells(configs: Sequence[SimConfig], grid, duration: float,
     ``pad_to`` (a ``_pad_spec`` dict, possibly from a larger grid) pins the
     padded shapes so different chunks of one sharded run stay signature-
     compatible with each other."""
-    kind = configs[0].kind
-    if any(c.kind != kind for c in configs):
-        raise ValueError("cannot mix group and epaxos kernels in one batch")
-    spec = pad_to or _pad_spec(configs, grid)
-    nreg = spec["nreg"]
-    kmax = spec["kmax"]
-    stop = warmup + duration
-    cells: Dict[str, list] = {k: [] for k in (
-        "sizes", "thresh", "grp", "pos", "gstart", "regF", "reg_lat",
-        "leader_reg", "jitter", "costs",
-        "majority", "n_groups", "static_relay", "k_clients", "key", "stop",
-        "warmup", "duration", "n_followers", "reg_nodes", "fq",
-        "w_follower", "downL", "downF", "slowF", "slowL",
-        "key_mode", "n_keys", "conflict_rate", "key_cdf", "read_ratio")}
-    wmax = spec["wmax"]
-    rmax, fmax = spec["rmax"], spec["fmax"]
-    nmax, nkeys_max = spec["nmax"], spec["nkeys_max"]
-    if kind == "epaxos" and any(c.n != nmax for c in configs):
-        raise ValueError("epaxos batches must share one cluster size")
-    for ci, k, seed in grid:
-        c = configs[ci]
-        sizes = np.zeros(rmax, np.int32)
-        thresh = np.zeros(rmax, np.int32)
-        # flat group-contiguous follower layout (padding at the tail keeps
-        # segment scans confined to real slots)
-        grp = np.full(fmax, max(rmax - 1, 0), np.int32)
-        pos = np.full(fmax, 1, np.int32)      # non-zero: never a segment start
-        gstart = np.zeros(rmax, np.int32)
-        regf = np.zeros(fmax, np.int32)
-        # fault masks in flat-slot layout (inf-padded = never down)
-        downf = np.full((fmax, wmax, 2), np.inf, np.float32)
-        slowf = np.zeros(fmax, np.float32)
-        downl = np.full((wmax, 2), np.inf, np.float32)
-        slowl = np.float32(0.0)
-        if kind == "group":
-            sizes[:c.rmax] = c.sizes
-            thresh[:c.rmax] = c.thresh
-            off = 0
-            for gi in range(c.rmax):
-                sz = int(c.sizes[gi])
-                grp[off:off + sz] = gi
-                pos[off:off + sz] = np.arange(sz)
-                gstart[gi] = off
-                members = c.members[gi, :sz]
-                regf[off:off + sz] = c.region_of[members]
+    with TraceAnnotation("vectorsim.stack"):
+        kind = configs[0].kind
+        if any(c.kind != kind for c in configs):
+            raise ValueError("cannot mix group and epaxos kernels in one "
+                             "batch")
+        spec = pad_to or _pad_spec(configs, grid)
+        nreg = spec["nreg"]
+        kmax = spec["kmax"]
+        stop = warmup + duration
+        cells: Dict[str, list] = {k: [] for k in (
+            "sizes", "thresh", "grp", "pos", "gstart", "regF", "reg_lat",
+            "leader_reg", "jitter", "costs",
+            "majority", "n_groups", "static_relay", "k_clients", "key", "stop",
+            "warmup", "duration", "n_followers", "reg_nodes", "fq",
+            "w_follower", "downL", "downF", "slowF", "slowL",
+            "key_mode", "n_keys", "conflict_rate", "key_cdf", "read_ratio")}
+        wmax = spec["wmax"]
+        rmax, fmax = spec["rmax"], spec["fmax"]
+        nmax, nkeys_max = spec["nmax"], spec["nkeys_max"]
+        if kind == "epaxos" and any(c.n != nmax for c in configs):
+            raise ValueError("epaxos batches must share one cluster size")
+        for ci, k, seed in grid:
+            c = configs[ci]
+            sizes = np.zeros(rmax, np.int32)
+            thresh = np.zeros(rmax, np.int32)
+            # flat group-contiguous follower layout (padding at the tail keeps
+            # segment scans confined to real slots)
+            grp = np.full(fmax, max(rmax - 1, 0), np.int32)
+            pos = np.full(fmax, 1, np.int32)  # non-zero: never a segment start
+            gstart = np.zeros(rmax, np.int32)
+            regf = np.zeros(fmax, np.int32)
+            # fault masks in flat-slot layout (inf-padded = never down)
+            downf = np.full((fmax, wmax, 2), np.inf, np.float32)
+            slowf = np.zeros(fmax, np.float32)
+            downl = np.full((wmax, 2), np.inf, np.float32)
+            slowl = np.float32(0.0)
+            if kind == "group":
+                sizes[:c.rmax] = c.sizes
+                thresh[:c.rmax] = c.thresh
+                off = 0
+                for gi in range(c.rmax):
+                    sz = int(c.sizes[gi])
+                    grp[off:off + sz] = gi
+                    pos[off:off + sz] = np.arange(sz)
+                    gstart[gi] = off
+                    members = c.members[gi, :sz]
+                    regf[off:off + sz] = c.region_of[members]
+                    if c.down is not None:
+                        downf[off:off + sz, :c.down.shape[1]] = c.down[members]
+                    if c.slow is not None:
+                        slowf[off:off + sz] = c.slow[members]
+                    off += sz
+                gstart[c.rmax:] = off
                 if c.down is not None:
-                    downf[off:off + sz, :c.down.shape[1]] = c.down[members]
+                    downl[:c.down.shape[1]] = c.down[0]
                 if c.slow is not None:
-                    slowf[off:off + sz] = c.slow[members]
-                off += sz
-            gstart[c.rmax:] = off
-            if c.down is not None:
-                downl[:c.down.shape[1]] = c.down[0]
-            if c.slow is not None:
-                slowl = np.float32(c.slow[0])
-        rl = np.zeros((nreg, nreg), np.float64)
-        nr = c.region_latency.shape[0]
-        rl[:nr, :nr] = c.region_latency
-        cells["sizes"].append(sizes)
-        cells["thresh"].append(thresh)
-        cells["grp"].append(grp)
-        cells["pos"].append(pos)
-        cells["gstart"].append(gstart)
-        cells["regF"].append(regf)
-        cells["downL"].append(downl)
-        cells["downF"].append(downf)
-        cells["slowF"].append(slowf)
-        cells["slowL"].append(slowl)
-        cells["reg_lat"].append(rl.astype(np.float32))
-        cells["leader_reg"].append(np.int32(c.region_of[0]))
-        cells["jitter"].append(np.float32(c.jitter))
-        if kind == "group":
-            order = ("c_req", "c_fanout", "c_rel", "c_repl", "c_agg",
-                     "c_replycl")
-        else:
-            order = ("c_req", "c_pa", "c_par", "c_com", "c_replycl",
-                     "c_acc", "c_accr")
-        cells["costs"].append(np.asarray([c.costs[o] for o in order],
-                                         np.float32))
-        cells["key_mode"].append(np.int32(c.key_mode))
-        cells["n_keys"].append(np.int32(c.n_keys if kind == "epaxos" else 1))
-        cells["conflict_rate"].append(np.float32(c.conflict_rate))
-        cdf = np.ones(nkeys_max, np.float32)
-        if kind == "epaxos" and c.key_cdf is not None:
-            cdf[:len(c.key_cdf)] = np.asarray(c.key_cdf, np.float32)
-        cells["key_cdf"].append(cdf)
-        cells["majority"].append(np.int32(c.majority))
-        cells["n_groups"].append(np.int32(int((c.sizes > 0).sum())))
-        cells["static_relay"].append(np.bool_(c.static_relay))
-        cells["k_clients"].append(np.int32(k))
-        cells["key"].append(np.asarray(
-            jax.random.PRNGKey(int(seed) * 1_000_003 + ci)))
-        cells["stop"].append(np.float32(stop))
-        cells["warmup"].append(np.float32(warmup))
-        cells["duration"].append(np.float32(duration))
-        cells["n_followers"].append(np.int32(c.n - 1))
-        if kind == "group":
-            szs = c.sizes[c.sizes > 0].astype(float)
-            wf = (len(szs) * (c.costs["c_fanout"] + c.costs["c_agg"])
-                  + 2.0 * float((szs - 1).sum())
-                  * (c.costs["c_rel"] + c.costs["c_repl"])) / max(c.n - 1, 1)
-            # leased reads add no follower work: the utilization estimate
-            # sees per-op work scaled to the write fraction
-            wf *= 1.0 - c.read_ratio
-        else:
-            wf = 0.0
-        cells["w_follower"].append(np.float32(wf))
-        cells["read_ratio"].append(np.float32(c.read_ratio))
-        cells["reg_nodes"].append(
-            np.asarray(c.region_of[:nmax] if kind == "epaxos"
-                       else np.zeros(1), np.int32))
-        cells["fq"].append(np.int32(fast_quorum(c.n)))
-    batch = {k: np.stack(v) for k, v in cells.items()}
-    return batch, kind, kmax
+                    slowl = np.float32(c.slow[0])
+            rl = np.zeros((nreg, nreg), np.float64)
+            nr = c.region_latency.shape[0]
+            rl[:nr, :nr] = c.region_latency
+            cells["sizes"].append(sizes)
+            cells["thresh"].append(thresh)
+            cells["grp"].append(grp)
+            cells["pos"].append(pos)
+            cells["gstart"].append(gstart)
+            cells["regF"].append(regf)
+            cells["downL"].append(downl)
+            cells["downF"].append(downf)
+            cells["slowF"].append(slowf)
+            cells["slowL"].append(slowl)
+            cells["reg_lat"].append(rl.astype(np.float32))
+            cells["leader_reg"].append(np.int32(c.region_of[0]))
+            cells["jitter"].append(np.float32(c.jitter))
+            if kind == "group":
+                order = ("c_req", "c_fanout", "c_rel", "c_repl", "c_agg",
+                         "c_replycl")
+            else:
+                order = ("c_req", "c_pa", "c_par", "c_com", "c_replycl",
+                         "c_acc", "c_accr")
+            cells["costs"].append(np.asarray([c.costs[o] for o in order],
+                                             np.float32))
+            cells["key_mode"].append(np.int32(c.key_mode))
+            cells["n_keys"].append(
+                np.int32(c.n_keys if kind == "epaxos" else 1))
+            cells["conflict_rate"].append(np.float32(c.conflict_rate))
+            cdf = np.ones(nkeys_max, np.float32)
+            if kind == "epaxos" and c.key_cdf is not None:
+                cdf[:len(c.key_cdf)] = np.asarray(c.key_cdf, np.float32)
+            cells["key_cdf"].append(cdf)
+            cells["majority"].append(np.int32(c.majority))
+            cells["n_groups"].append(np.int32(int((c.sizes > 0).sum())))
+            cells["static_relay"].append(np.bool_(c.static_relay))
+            cells["k_clients"].append(np.int32(k))
+            cells["key"].append(np.asarray(
+                jax.random.PRNGKey(int(seed) * 1_000_003 + ci)))
+            cells["stop"].append(np.float32(stop))
+            cells["warmup"].append(np.float32(warmup))
+            cells["duration"].append(np.float32(duration))
+            cells["n_followers"].append(np.int32(c.n - 1))
+            if kind == "group":
+                szs = c.sizes[c.sizes > 0].astype(float)
+                wf = (len(szs) * (c.costs["c_fanout"] + c.costs["c_agg"])
+                      + 2.0 * float((szs - 1).sum())
+                      * (c.costs["c_rel"] + c.costs["c_repl"])) \
+                    / max(c.n - 1, 1)
+                # leased reads add no follower work: the utilization estimate
+                # sees per-op work scaled to the write fraction
+                wf *= 1.0 - c.read_ratio
+            else:
+                wf = 0.0
+            cells["w_follower"].append(np.float32(wf))
+            cells["read_ratio"].append(np.float32(c.read_ratio))
+            cells["reg_nodes"].append(
+                np.asarray(c.region_of[:nmax] if kind == "epaxos"
+                           else np.zeros(1), np.int32))
+            cells["fq"].append(np.int32(fast_quorum(c.n)))
+        batch = {k: np.stack(v) for k, v in cells.items()}
+        return batch, kind, kmax
 
 
 def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
@@ -1223,39 +1255,64 @@ def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
     "pallas"; see ``_group_cell``) — "auto" picks the Pallas kernel on TPU
     and the XLA sort path elsewhere.
     """
-    batch, kind, kmax = _stack_cells(configs, grid, duration, warmup)
-    kernel = _resolve_kernel(kernel, kind)
-    if obs and kind != "group":
-        raise ValueError("obs timelines are group-kernel only — the epaxos "
-                         "kernel has no single-leader FIFO to observe")
-    faulty = any(c.down is not None or c.slow is not None for c in configs)
-    read = any(c.read_ratio > 0.0 for c in configs)
-    nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
-          if (faulty or timeline or obs) else 0)
-    if steps is None:
-        # requests are only issued inside [0, stop); the rate bound is
-        # optimistic, and the exhausted-retry loop below is the safety net
-        rate = max(_estimate_rate(configs[ci], k) for ci, k, _ in grid)
-        steps = int(rate * (warmup + duration) * 1.15) + kmax + 64
-    steps = min(steps, _MAX_STEPS)
-    # the group kernel pops `breq` requests per scan step
-    breq = min(8, kmax) if kind == "group" else 1
-    out = _run_cells(batch, -(-steps // breq), kmax, kind, breq, faulty, nb,
-                     kernel, obs, read)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    steps_arr = np.full(len(grid), steps, np.int32)
-    if out["exhausted"].any():
-        out = {k: np.array(v) for k, v in out.items()}   # writable for merge
-    while out["exhausted"].any() and steps < _MAX_STEPS:
-        steps = min(steps * 2, _MAX_STEPS)
-        idx = np.nonzero(out["exhausted"])[0]
-        sub = {k: v[idx] for k, v in batch.items()}
-        sub_out = _run_cells(sub, -(-steps // breq), kmax, kind, breq,
-                             faulty, nb, kernel, obs, read)
-        for k, v in sub_out.items():
-            out[k][idx] = np.asarray(v)
-        steps_arr[idx] = steps
-    out["steps"] = steps_arr
+    with TraceAnnotation("vectorsim.grid") as span:
+        batch, kind, kmax = _stack_cells(configs, grid, duration, warmup)
+        kernel = _resolve_kernel(kernel, kind)
+        if obs and kind != "group":
+            raise ValueError("obs timelines are group-kernel only — the "
+                             "epaxos kernel has no single-leader FIFO to "
+                             "observe")
+        faulty = any(c.down is not None or c.slow is not None
+                     for c in configs)
+        read = any(c.read_ratio > 0.0 for c in configs)
+        nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
+              if (faulty or timeline or obs) else 0)
+        if steps is None:
+            # requests are only issued inside [0, stop); the rate bound is
+            # optimistic, and the exhausted-retry loop below is the safety
+            # net
+            with TraceAnnotation("vectorsim.budget"):
+                rate = max(_estimate_rate(configs[ci], k)
+                           for ci, k, _ in grid)
+            steps = int(rate * (warmup + duration) * 1.15) + kmax + 64
+        steps = min(steps, _MAX_STEPS)
+        # the group kernel pops `breq` requests per scan step
+        breq = min(8, kmax) if kind == "group" else 1
+
+        def run(b, scan_steps):
+            return _run_cells(b, scan_steps, kmax, kind, breq, faulty, nb,
+                              kernel, obs, read)
+
+        out = _pass(run, batch, steps, breq)
+        steps_arr = np.full(len(grid), steps, np.int32)
+        if out["exhausted"].any():
+            out = {k: np.array(v) for k, v in out.items()}  # writable
+        p = 0
+        while out["exhausted"].any() and steps < _MAX_STEPS:
+            p += 1
+            steps = min(steps * 2, _MAX_STEPS)
+            idx = np.nonzero(out["exhausted"])[0]
+            with TraceAnnotation("vectorsim.retry"):
+                sub = {k: v[idx] for k, v in batch.items()}
+                for k, v in _pass(run, sub, steps, breq).items():
+                    out[k][idx] = v
+            steps_arr[idx] = steps
+        out["steps"] = steps_arr
+        span.set_metadata(passes=p + 1)
+    return out
+
+
+def _pass(run, batch, steps: int, breq: int) -> Dict[str, np.ndarray]:
+    """One pass of the scan over ``batch`` at a budget of ``steps``
+    requests: ``run`` looks up the compiled program, copies the batch to
+    the device and enqueues it; the readback waits for the device and
+    copies the outputs out."""
+    scan_steps = -(-steps // breq)
+    with TraceAnnotation("vectorsim.run", scan_steps=scan_steps):
+        out = run(batch, scan_steps)
+    with TraceAnnotation("vectorsim.readback") as span:
+        out = {k: np.asarray(v) for k, v in out.items()}
+        span.set_metadata(exhausted=int(out["exhausted"].sum()))
     return out
 
 
@@ -1320,62 +1377,75 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
     count, kernel, chunk size, and per-chunk {cells, wall_s, steps} — the
     stream the megagrid study and the bench schema consume.
     """
-    devices = list(devices if devices is not None else jax.devices())
-    D = len(devices)
-    chunk = max(chunk - chunk % D, D)
-    kind = configs[0].kind
-    kernel = _resolve_kernel(kernel, kind)
-    spec = _pad_spec(configs, grid)
-    faulty = any(c.down is not None or c.slow is not None for c in configs)
-    read = any(c.read_ratio > 0.0 for c in configs)
-    nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
-          if (faulty or timeline) else 0)
-    if steps is None:
-        rate = max(_estimate_rate(configs[ci], k) for ci, k, _ in grid)
-        steps = int(rate * (warmup + duration) * 1.15) + spec["kmax"] + 64
-    steps0 = min(steps, _MAX_STEPS)
-    breq = min(8, spec["kmax"]) if kind == "group" else 1
+    with TraceAnnotation("vectorsim.grid") as span:
+        devices = list(devices if devices is not None else jax.devices())
+        D = len(devices)
+        chunk = max(chunk - chunk % D, D)
+        kind = configs[0].kind
+        kernel = _resolve_kernel(kernel, kind)
+        spec = _pad_spec(configs, grid)
+        faulty = any(c.down is not None or c.slow is not None
+                     for c in configs)
+        read = any(c.read_ratio > 0.0 for c in configs)
+        nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
+              if (faulty or timeline) else 0)
+        if steps is None:
+            with TraceAnnotation("vectorsim.budget"):
+                rate = max(_estimate_rate(configs[ci], k)
+                           for ci, k, _ in grid)
+            steps = int(rate * (warmup + duration) * 1.15) \
+                + spec["kmax"] + 64
+        steps0 = min(steps, _MAX_STEPS)
+        breq = min(8, spec["kmax"]) if kind == "group" else 1
 
-    n_cells = len(grid)
-    out: Dict[str, np.ndarray] = {}
-    steps_arr = np.empty(n_cells, np.int32)
-    meta = []
-    for lo in range(0, n_cells, chunk):
-        part = list(grid[lo:lo + chunk])
-        real = len(part)
-        part += [part[-1]] * (chunk - real)   # keep one static shape
-        batch, _, _ = _stack_cells(configs, part, duration, warmup,
-                                   pad_to=spec)
-        t0 = time.perf_counter()
-        steps_c = steps0
-        cout = _run_cells_sharded(batch, -(-steps_c // breq), spec["kmax"],
-                                  kind, breq, faulty, nb, kernel, devices,
-                                  read)
-        cout = {k: np.array(v) for k, v in cout.items()}
-        csteps = np.full(chunk, steps_c, np.int32)
-        while cout["exhausted"][:real].any() and steps_c < _MAX_STEPS:
-            steps_c = min(steps_c * 2, _MAX_STEPS)
-            idx = np.nonzero(cout["exhausted"])[0]
-            # retry the exhausted subset, padded back to a device multiple
-            ridx = np.resize(idx, -(-len(idx) // D) * D)
-            sub = {k: v[ridx] for k, v in batch.items()}
-            sub_out = _run_cells_sharded(sub, -(-steps_c // breq),
-                                         spec["kmax"], kind, breq, faulty,
-                                         nb, kernel, devices, read)
-            for k, v in sub_out.items():
-                cout[k][idx] = np.asarray(v)[:len(idx)]
-            csteps[idx] = steps_c
-        wall = time.perf_counter() - t0
-        for k, v in cout.items():
-            if k not in out:
-                out[k] = np.empty((n_cells,) + v.shape[1:], v.dtype)
-            out[k][lo:lo + real] = v[:real]
-        steps_arr[lo:lo + real] = csteps[:real]
-        meta.append({"cells": real, "wall_s": wall,
-                     "steps": int(csteps[:real].max())})
-    out["steps"] = steps_arr
-    out["sharding"] = {"devices": D, "kernel": kernel,
-                       "chunk": chunk, "chunks": meta}
+        def run(b, scan_steps):
+            return _run_cells_sharded(b, scan_steps, spec["kmax"], kind,
+                                      breq, faulty, nb, kernel, devices,
+                                      read)
+
+        n_cells = len(grid)
+        out: Dict[str, np.ndarray] = {}
+        steps_arr = np.empty(n_cells, np.int32)
+        meta = []
+        passes = 0
+        for lo in range(0, n_cells, chunk):
+            part = list(grid[lo:lo + chunk])
+            real = len(part)
+            part += [part[-1]] * (chunk - real)   # keep one static shape
+            with TraceAnnotation("vectorsim.chunk"):
+                batch, _, _ = _stack_cells(configs, part, duration, warmup,
+                                           pad_to=spec)
+                t0 = time.perf_counter()
+                steps_c, p = steps0, 0
+                cout = _pass(run, batch, steps_c, breq)
+                if cout["exhausted"].any():
+                    cout = {k: np.array(v) for k, v in cout.items()}
+                csteps = np.full(chunk, steps_c, np.int32)
+                while cout["exhausted"][:real].any() and steps_c < _MAX_STEPS:
+                    p += 1
+                    steps_c = min(steps_c * 2, _MAX_STEPS)
+                    idx = np.nonzero(cout["exhausted"])[0]
+                    with TraceAnnotation("vectorsim.retry"):
+                        # the exhausted subset, padded back to a device
+                        # multiple
+                        ridx = np.resize(idx, -(-len(idx) // D) * D)
+                        sub = {k: v[ridx] for k, v in batch.items()}
+                        for k, v in _pass(run, sub, steps_c, breq).items():
+                            cout[k][idx] = v[:len(idx)]
+                    csteps[idx] = steps_c
+                wall = time.perf_counter() - t0
+            passes += p + 1
+            for k, v in cout.items():
+                if k not in out:
+                    out[k] = np.empty((n_cells,) + v.shape[1:], v.dtype)
+                out[k][lo:lo + real] = v[:real]
+            steps_arr[lo:lo + real] = csteps[:real]
+            meta.append({"cells": real, "wall_s": wall,
+                         "steps": int(csteps[:real].max())})
+        out["steps"] = steps_arr
+        out["sharding"] = {"devices": D, "kernel": kernel,
+                           "chunk": chunk, "chunks": meta}
+        span.set_metadata(passes=passes)
     return out
 
 

@@ -22,8 +22,7 @@ then each bucket streams through the device-sharded runner chunk by chunk
 (donated inputs, bounded device memory).  Results aggregate into ONE
 ``repro-experiments/v1`` artifact — per-point curve entries under the
 ``megagrid`` family plus a ``megagrid`` section with per-chunk walls,
-cells/s, device count, kernel flag, and a roofline note locating the run
-against this host's measured compute/memory ceilings.
+cells/s, device count and kernel flag.
 
 CLI:  ``python -m repro.experiments.megagrid --cells 1000000 --out FILE``
 (``--preset smoke`` is the CI slice).  On GPU/TPU hosts the same command
@@ -139,71 +138,6 @@ def _bucket_key(pt: dict, k: int) -> tuple:
     return ("group", fcls, kcls, wan)
 
 
-# ------------------------------------------------------------------ roofline
-def measure_ceilings() -> Dict[str, float]:
-    """Empirical single-host ceilings the roofline note is drawn against:
-    peak f32 GEMM throughput (compute) and large-array streaming bandwidth
-    (memory).  Measured, not quoted — the container's one CPU core is the
-    'hardware limit' the acceptance speaks of."""
-    import jax
-    import jax.numpy as jnp
-    m = 1024
-    a = jnp.ones((m, m), jnp.float32)
-    f = jax.jit(lambda x: x @ x)
-    jax.block_until_ready(f(a))
-    t0 = time.perf_counter()
-    reps = 8
-    for _ in range(reps):
-        jax.block_until_ready(f(a))
-    gemm_s = (time.perf_counter() - t0) / reps
-    x = jnp.ones((32 * 1024 * 1024,), jnp.float32)      # 128 MiB
-    g = jax.jit(lambda a, b: a + b)
-    jax.block_until_ready(g(x, x))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        jax.block_until_ready(g(x, x))
-    add_s = (time.perf_counter() - t0) / reps
-    return {
-        "peak_flops": 2.0 * m ** 3 / gemm_s,            # f32 FMA ceiling
-        "peak_bytes_per_s": 3.0 * x.size * 4 / add_s,   # 2 reads + 1 write
-    }
-
-
-def _cell_step_ops(kind: str, F: int, G: int, B: int) -> float:
-    """Model op count of one scan step of one cell (element-ops, counted
-    from the kernel body: ~70 (B,F)-shaped passes + ~30 (B,G) + threefry
-    RNG at ~40 ops/draw + the O(F log^2 F) sort network).  An estimate for
-    the roofline NOTE, not a profile."""
-    if kind == "epaxos":
-        n = F            # callers pass n as F for the epaxos kernel
-        return 40.0 * (2 * n + 4) + 60.0 * n
-    logf = max(np.log2(max(F, 2)), 1.0)
-    return (40.0 * B * (2 + 2 * G + 2 * F)      # threefry jitter draws
-            + 70.0 * B * F + 30.0 * B * G       # elementwise pipeline
-            + 2.0 * B * F * logf * logf)        # lexicographic sort
-
-def roofline_note(buckets: List[dict], ceilings: Dict[str, float]) -> dict:
-    """How far from the hardware limit the batch backend lands: achieved
-    element-ops/s (model count / measured wall) against the measured GEMM
-    ceiling, and the implied bytes/s (4 B per element-op, ~1.5 access
-    amplification) against the streaming ceiling."""
-    ops = sum(b["est_ops"] for b in buckets)
-    wall = sum(b["wall_s"] for b in buckets)
-    achieved = ops / max(wall, 1e-9)
-    bytes_ps = achieved * 4.0 * 1.5
-    f_c = achieved / ceilings["peak_flops"]
-    f_m = bytes_ps / ceilings["peak_bytes_per_s"]
-    return {
-        "est_element_ops": ops,
-        "achieved_gops": round(achieved / 1e9, 3),
-        "peak_gflops": round(ceilings["peak_flops"] / 1e9, 1),
-        "peak_stream_gbps": round(ceilings["peak_bytes_per_s"] / 1e9, 1),
-        "frac_of_compute_roof": round(f_c, 4),
-        "frac_of_memory_roof": round(f_m, 4),
-        "bound": "memory" if f_m >= f_c else "compute",
-    }
-
-
 # ------------------------------------------------------------------ the run
 def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
                  chunk: int = 4096, kernel: str = "auto",
@@ -263,19 +197,10 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             }
         ncell = len(grid)
         total_cells += ncell
-        kind = "epaxos" if bkey[0] == "epaxos" else "group"
-        if kind == "group":
-            F, B = bkey[1], min(8, bkey[2])
-            G = max(c.rmax for c in cfgs)
-        else:
-            F, G, B = bkey[1], 1, 1
         steps = float(np.mean([m["steps"] for m in
                                out["sharding"]["chunks"]]))
-        breq = min(8, bkey[2]) if kind == "group" else 1
-        est = ncell * (steps / breq) * _cell_step_ops(kind, F, G, B)
         bmeta.append({"bucket": list(map(str, bkey)), "cells": ncell,
-                      "wall_s": round(wall, 2), "est_ops": est,
-                      "steps": int(steps),
+                      "wall_s": round(wall, 2), "steps": int(steps),
                       "chunks": len(out["sharding"]["chunks"])})
         all_chunks += [{"bucket": str(bkey), **m}
                        for m in out["sharding"]["chunks"]]
@@ -284,7 +209,6 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
                      f"({ncell / max(wall, 1e-9):.0f} cells/s)")
 
     wall_total = time.perf_counter() - t_start
-    ceilings = measure_ceilings()
     per_cell_ms = wall_total / max(total_cells, 1) * 1e3
     scenarios = []
     for pi, p in enumerate(pts):
@@ -332,7 +256,6 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             "duration_s": duration, "warmup_s": warmup,
             "buckets": bmeta,
             "chunk_walls": all_chunks,
-            "roofline": roofline_note(bmeta, ceilings),
         },
     }
 
@@ -360,9 +283,6 @@ def main(argv=None) -> int:
           f"({mg['cells_per_s']} cells/s, {mg['per_cell_ms']} ms/cell; "
           f"{mg['speedup_per_cell']}x the committed 384-cell baseline) "
           f"-> {args.out}")
-    # an op-count model against host-measured ceilings, not a chip reading
-    print(f"[megagrid] modelled roofline note (not a device measurement): "
-          f"{mg['roofline']}")
     return 0
 
 

@@ -8,8 +8,12 @@ these compiles guard the fan-in kernel at the widths the model uses
 (F = 24 -> 128 at N=25, F = 1024 at N=1025) and the whole scan step
 around it.  Code that asks ``jax.default_backend()`` still sees the CPU,
 so the group step is steered onto the native kernel by patching
-``kernels.ops._interpret`` inside the test.
+``kernels.ops._interpret`` inside the fixture.  The compiled steps also
+show that the scan's stage scopes reach each operation's ``op_name``
+metadata and lend no operation the fan-in kernel's name.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -70,18 +74,58 @@ def test_seg_fanin_compiles_native(one_chip, B, F):
     assert "tpu_custom_call" in text
 
 
-def test_group_step_compiles_with_native_fanin(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def group_step(one_chip):
     """One whole PigPaxos N=25 scan step program with the Pallas fan-in
     lowered natively (the path ``kernel="auto"`` takes on a TPU)."""
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    cfgs = [vs.build_config("pigpaxos", 25, pig=PigConfig(n_groups=3,
-                                                           prc=1))]
-    grid = [(0, 24, s) for s in range(3)]
-    assert "tpu_custom_call" in _compile_step(one_chip, cfgs, grid, "pallas")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        cfgs = [vs.build_config("pigpaxos", 25, pig=PigConfig(n_groups=3,
+                                                               prc=1))]
+        grid = [(0, 24, s) for s in range(3)]
+        return _compile_step(one_chip, cfgs, grid, "pallas")
 
 
-def test_epaxos_step_compiles(one_chip):
+@pytest.fixture(scope="module")
+def epaxos_step(one_chip):
     cfgs = [vs.build_config("epaxos", 25, workload=WorkloadConfig(
         key_dist="conflict", conflict_rate=0.1))]
     grid = [(0, 24, s) for s in range(3)]
-    assert "while" in _compile_step(one_chip, cfgs, grid, "lax")
+    return _compile_step(one_chip, cfgs, grid, "lax")
+
+
+def test_group_step_compiles_with_native_fanin(group_step):
+    assert "tpu_custom_call" in group_step
+
+
+def test_epaxos_step_compiles(epaxos_step):
+    assert "while" in epaxos_step
+
+
+# the scan's stage scopes (jax.named_scope in _group_cell / _epaxos_cell)
+STAGES = {"group_step": ("ingress", "relay_pick", "relay_fanout",
+                         "relay_acks", "commit", "state", "summary"),
+          "epaxos_step": ("keys", "preaccept", "conflict", "exec_gate",
+                          "state", "summary")}
+
+
+@pytest.mark.parametrize("step", sorted(STAGES))
+def test_stage_scopes_reach_the_op_name_metadata(request, step):
+    text = request.getfixturevalue(step)
+    scopes = {part.rsplit("(", 1)[-1].rstrip(")")
+              for path in re.findall(r'op_name="([^"]*)"', text)
+              for part in path.split("/")}
+    assert set(STAGES[step]) <= scopes
+
+
+@pytest.mark.parametrize("step", sorted(STAGES))
+def test_only_the_pallas_call_is_named_fanin(request, step):
+    """``fanin_us`` sums the operations whose name holds "fanin": the
+    scopes must not lend that word to any other operation."""
+    text = request.getfixturevalue(step)
+    named = re.findall(r"^\s*(?:ROOT )?%(\S*fanin\S*) = (.*)$", text, re.M)
+    want = ["seg_fanin_bf"] if step == "group_step" else []
+    assert [n.split(".")[0] for n, _ in named] == want
+    assert all('custom_call_target="tpu_custom_call"' in rest
+               for _, rest in named)
+    assert not any("fanin" in s for s in STAGES[step])
