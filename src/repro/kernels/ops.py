@@ -10,6 +10,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .flash_attention import flash_attention_bhsd
 from .pig_aggregate import pig_aggregate as _pig_aggregate_kernel
@@ -104,8 +105,14 @@ def seg_fanin(vals: jax.Array, coef: jax.Array, segid: jax.Array,
     scal = jnp.stack([vcoef * ones, md1 * ones, c * ones,
                       jnp.broadcast_to(jnp.asarray(anchor, f32), (B,))],
                      axis=1)
-    out = seg_fanin_bf(vals, coef,
-                       jnp.broadcast_to(sid[None, :], (B, F + pad)),
-                       jnp.broadcast_to(kc[None, :], (B, F + pad)),
-                       scal, interpret=_interpret())
+    seglen = None
+    if F + pad > 128:
+        # the longest segment bounds the kernel's lane shifts on a wide tile
+        idx = jnp.arange(F)
+        first = jnp.concatenate([jnp.ones((1,), bool),
+                                 segid[1:] != segid[:-1]])
+        start = lax.cummax(jnp.where(first, idx, 0), axis=0)
+        seglen = (jnp.max(idx - start) + 1).astype(jnp.int32).reshape(1, 1)
+    out = seg_fanin_bf(vals, coef, sid[None, :], kc[None, :], scal, seglen,
+                       nslots=F, interpret=_interpret())
     return out[:, :F]

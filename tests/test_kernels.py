@@ -147,21 +147,31 @@ def test_pig_aggregate_vs_ref(G, N, block):
 
 
 # ---------------------------------------------------------- seg fan-in
-def _fanin_case(key, B, G, gsize, mask_per_seg=0):
-    """A vectorsim-shaped burst: F = G*gsize contiguous slots, segment-
-    constant coef/kcap, optionally one +inf-masked slot per segment."""
-    F = G * gsize
+# the sort + segmented-scan oracle, compiled once per shape rather than op
+# by op (same operations, same results; most of these tests' CPU time)
+_seg_fanin_ref = jax.jit(ref.seg_fanin_ref)
+
+
+def _fanin_case(key, B, sizes, mask_per_seg=0):
+    """A vectorsim-shaped burst: contiguous segments of the given sizes,
+    segment-constant coef/kcap, optionally one +inf-masked slot per
+    segment of two or more slots (masking a one-slot segment would leave
+    it no admissible entry)."""
+    sizes = np.asarray(sizes)
+    G, F = len(sizes), int(sizes.sum())
     ks = jax.random.split(key, 4)
     vals = jax.random.uniform(ks[0], (B, F), jnp.float32, 1.0, 2.0)
-    segid = jnp.repeat(jnp.arange(G), gsize)
-    coef = jnp.repeat(jax.random.uniform(ks[1], (B, G), jnp.float32,
-                                         0.0, 1e-3), gsize, axis=1)
-    kcap = jnp.repeat(
-        jax.random.randint(ks[2], (G,), 0, gsize - mask_per_seg),
-        gsize).astype(jnp.float32)
-    if mask_per_seg:
-        drop = jax.random.randint(ks[3], (G,), 0, gsize)
-        vals = vals.at[:, drop + jnp.arange(G) * gsize].set(jnp.inf)
+    segid = jnp.asarray(np.repeat(np.arange(G), sizes))
+    coef = jnp.asarray(np.repeat(np.asarray(jax.random.uniform(
+        ks[1], (B, G), jnp.float32, 0.0, 1e-3)), sizes, axis=1))
+    masked = mask_per_seg * (sizes >= 2)
+    kcap = jnp.asarray(np.repeat(np.asarray(jax.random.randint(
+        ks[2], (G,), 0, np.maximum(sizes - masked, 1))), sizes),
+        jnp.float32)
+    if masked.any():
+        start = np.cumsum(sizes) - sizes
+        drop = start + np.asarray(jax.random.randint(ks[3], (G,), 0, sizes))
+        vals = vals.at[:, drop[masked > 0]].set(jnp.inf)
     anchor = jnp.full((B,), 1.0, jnp.float32)
     return (vals, coef, segid, kcap, -0.5, 3e-4, 2e-5, anchor)
 
@@ -169,17 +179,31 @@ def _fanin_case(key, B, G, gsize, mask_per_seg=0):
 @pytest.mark.parametrize("B,G,gsize", [
     (1, 1, 4),        # single segment
     (4, 3, 8),        # a 4-client burst (megagrid k=4 bucket), N=25 R=3
-    (8, 4, 6),        # the production shape (N=25, R=4)
+    (8, 4, 6),        # N=25, R=4
     (8, 8, 16),       # wide, pads 128 -> 128 exactly
     (3, 5, 7),        # odd everything (padding path, 35 -> 128)
+    # the benchmark's N=25 bursts: B=8, F=24 padded to 128
+    (8, 24, 1),       # MultiPaxos: 24 one-slot segments
+    (8, 2, 12),       # PigPaxos R=2
+    (8, 3, 8),        # PigPaxos R=3
+    (8, 5, (5, 5, 5, 5, 4)),   # PigPaxos R=5: the ragged partition of 24
+    (8, 1, 24),       # one group of all 24 followers
+    (8, 1, 128),      # one 128-slot segment: every lane shift, wraps the tile
+    # wider than 128 lanes: the shifts run in a loop bounded by the data's
+    # longest segment, 16 an iteration
+    (2, 10, 26),      # 260 -> 384: 50 shifts, the last iteration partly past
+    (2, 5, (5, 40, 3, 130, 82)),   # ragged, the longest segment not first
+    (1, 1, 256),      # one 256-slot segment: every lane shift, wraps the tile
 ])
 @pytest.mark.parametrize("mask", [0, 1])
 def test_seg_fanin_vs_ref(B, G, gsize, mask):
-    args = _fanin_case(jax.random.PRNGKey(B * 100 + G * 10 + gsize),
-                       B, G, gsize, mask_per_seg=mask)
+    """Kernel == the sort + segmented-scan oracle, bit for bit."""
+    sizes = (gsize,) * G if isinstance(gsize, int) else gsize
+    args = _fanin_case(jax.random.PRNGKey(B * 100 + G * 10 + max(sizes)),
+                       B, sizes, mask_per_seg=mask)
     got = np.asarray(ops.seg_fanin(*args))
-    want = np.asarray(ref.seg_fanin_ref(*args))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    want = np.asarray(_seg_fanin_ref(*args))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_seg_fanin_ties_match_stable_sort():
@@ -194,7 +218,7 @@ def test_seg_fanin_ties_match_stable_sort():
     anchor = jnp.ones((B,), jnp.float32)
     args = (vals, coef, segid, kcap, -0.5, 3e-4, 2e-5, anchor)
     np.testing.assert_array_equal(np.asarray(ops.seg_fanin(*args)),
-                                  np.asarray(ref.seg_fanin_ref(*args)))
+                                  np.asarray(_seg_fanin_ref(*args)))
 
 
 def test_seg_fanin_empty_admissible_set_is_neg_inf():
@@ -233,8 +257,8 @@ def test_seg_fanin_property(B, sizes, salt):
     args = (vals, coef, segid, kcap, -0.3, 1e-4, 3e-5,
             jnp.full((B,), 0.5, jnp.float32))
     got = np.asarray(ops.seg_fanin(*args))
-    want = np.asarray(ref.seg_fanin_ref(*args))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    want = np.asarray(_seg_fanin_ref(*args))
+    np.testing.assert_array_equal(got, want)
 
 
 @settings(max_examples=20, deadline=None)

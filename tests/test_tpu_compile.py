@@ -64,14 +64,24 @@ def _compile_step(one_chip, cfgs, grid, kernel):
     return lowered.compile().as_text()
 
 
-@pytest.mark.parametrize("B,F", [(4, 128), (8, 128), (8, 1024)])
+@pytest.mark.parametrize("B,F", [(4, 128), (8, 128), (8, 1024), (8, 24)])
 def test_seg_fanin_compiles_native(one_chip, B, F):
-    tile = jax.ShapeDtypeStruct((B, F), jnp.float32, sharding=one_chip)
-    scal = jax.ShapeDtypeStruct((B, 4), jnp.float32, sharding=one_chip)
-    fn = jax.jit(lambda v, u, s, k, c: seg_fanin_bf(v, u, s, k, c,
-                                                    interpret=False))
-    text = fn.lower(tile, tile, tile, tile, scal).compile().as_text()
-    assert "tpu_custom_call" in text
+    """F real slots padded to whole lanes: (8, 24) is the benchmark's
+    N=25 burst, (8, 1024) an N=1025 one, whose shifts run in a loop
+    bounded by the longest segment.  One tile, and a grid of two as the
+    scan's ``vmap`` over cells makes it."""
+    Fp = -(-F // 128) * 128
+    fn = jax.jit(lambda v, u, s, k, c, n: seg_fanin_bf(
+        v, u, s, k, c, n, nslots=F, interpret=False))
+    for cells in ((), (2,)):
+        def struct(*shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(cells + shape, dtype,
+                                        sharding=one_chip)
+        f = jax.vmap(fn) if cells else fn
+        text = jax.jit(f).lower(
+            struct(B, Fp), struct(B, Fp), struct(1, Fp), struct(1, Fp),
+            struct(B, 4), struct(1, 1, dtype=jnp.int32)).compile().as_text()
+        assert "tpu_custom_call" in text
 
 
 @pytest.fixture(scope="module")
