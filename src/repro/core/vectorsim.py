@@ -672,19 +672,21 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
             # time t0 would let closed-loop reissue waves masquerade as
             # backlog the leader's serialization actually paces out.
             # LAN batches (reg_lat is 1x1 — a static shape) skip every region
-            # gather: all link bases collapse to one scalar
+            # gather: all link bases collapse to one scalar.  The WAN's
+            # region lookups carry a "regions" scope inside their stage
             lan = reg_lat.shape[0] == 1
             if lan:
                 b_Lr = b_rL = reg_lat[0, 0]
                 b_rp = b_pr = reg_lat[0, 0]
             else:
-                reg_relay = regF[rel_idx]                     # (B, G)
-                b_Lr = reg_lat[leader_reg, reg_relay]
-                b_rL = reg_lat[reg_relay, leader_reg]
-                # per-direction bases: one-way matrices may be asymmetric
-                reg_relay_f = jnp.take_along_axis(reg_relay, grp_b, axis=1)
-                b_rp = reg_lat[reg_relay_f, regF[None, :]]    # (B, F) out
-                b_pr = reg_lat[regF[None, :], reg_relay_f]    # (B, F) back
+                with jax.named_scope("regions"):
+                    reg_relay = regF[rel_idx]                 # (B, G)
+                    b_Lr = reg_lat[leader_reg, reg_relay]
+                    # per-direction bases: one-way matrices may be asymmetric
+                    reg_relay_f = jnp.take_along_axis(reg_relay, grp_b,
+                                                      axis=1)
+                    b_rp = reg_lat[reg_relay_f, regF[None, :]]    # (B, F) out
+                    b_pr = reg_lat[regF[None, :], reg_relay_f]    # (B, F) back
             arr_rel = fan_done + b_Lr + e_Lr
             if faulty:
                 slow_rel = slowF[rel_idx]                     # (B, G)
@@ -756,6 +758,9 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
                     done_k, jnp.broadcast_to(t_idx, (B, G)), axis=1)
             flush = jnp.where((thresh >= 2)[None, :], done_g, relay_free0)
             agg_sent = flush + c_agg
+            if not lan:
+                with jax.named_scope("regions"):
+                    b_rL = reg_lat[reg_relay, leader_reg]     # (B, G)
 
         with jax.named_scope("commit"):
             # leader FIFO over aggregates; commit at the quorum-completing one
@@ -1294,21 +1299,33 @@ def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
             idx = np.nonzero(out["exhausted"])[0]
             with TraceAnnotation("vectorsim.retry"):
                 sub = {k: v[idx] for k, v in batch.items()}
-                for k, v in _pass(run, sub, steps, breq).items():
+                for k, v in _pass(run, sub, steps, breq, retry=True).items():
                     out[k][idx] = v
             steps_arr[idx] = steps
         out["steps"] = steps_arr
-        span.set_metadata(passes=p + 1)
+        span.set_metadata(passes=p + 1,
+                          **_region_count(batch["reg_lat"].shape[-1]))
     return out
 
 
-def _pass(run, batch, steps: int, breq: int) -> Dict[str, np.ndarray]:
+def _region_count(nreg: int) -> Dict[str, int]:
+    """The ``regions`` counter of a grid span: the grid's largest region
+    count, written only where the grid spans more than one region."""
+    return {"regions": nreg} if nreg > 1 else {}
+
+
+def _pass(run, batch, steps: int, breq: int,
+          retry: bool = False) -> Dict[str, np.ndarray]:
     """One pass of the scan over ``batch`` at a budget of ``steps``
     requests: ``run`` looks up the compiled program, copies the batch to
     the device and enqueues it; the readback waits for the device and
-    copies the outputs out."""
+    copies the outputs out.  The run span of an exhausted-retry pass also
+    counts the ``cells`` it re-runs."""
     scan_steps = -(-steps // breq)
-    with TraceAnnotation("vectorsim.run", scan_steps=scan_steps):
+    meta = {"scan_steps": scan_steps}
+    if retry:
+        meta["cells"] = len(batch["key"])
+    with TraceAnnotation("vectorsim.run", **meta):
         out = run(batch, scan_steps)
     with TraceAnnotation("vectorsim.readback") as span:
         out = {k: np.asarray(v) for k, v in out.items()}
@@ -1430,7 +1447,8 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
                         # multiple
                         ridx = np.resize(idx, -(-len(idx) // D) * D)
                         sub = {k: v[ridx] for k, v in batch.items()}
-                        for k, v in _pass(run, sub, steps_c, breq).items():
+                        for k, v in _pass(run, sub, steps_c, breq,
+                                          retry=True).items():
                             cout[k][idx] = v[:len(idx)]
                     csteps[idx] = steps_c
                 wall = time.perf_counter() - t0
@@ -1445,7 +1463,7 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
         out["steps"] = steps_arr
         out["sharding"] = {"devices": D, "kernel": kernel,
                            "chunk": chunk, "chunks": meta}
-        span.set_metadata(passes=passes)
+        span.set_metadata(passes=passes, **_region_count(spec["nreg"]))
     return out
 
 

@@ -21,7 +21,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from repro.core import PigConfig, WorkloadConfig  # noqa: E402
+from repro.core import PigConfig, WorkloadConfig, wan_topology  # noqa: E402
 from repro.core import vectorsim as vs  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.segfanin import seg_fanin_bf  # noqa: E402
@@ -104,6 +104,22 @@ def epaxos_step(one_chip):
     return _compile_step(one_chip, cfgs, grid, "lax")
 
 
+@pytest.fixture(scope="module")
+def wan_step(one_chip):
+    """The Fig. 10 deployment's step: Paxos and PigPaxos with one relay
+    group per region (4/5/5) over three regions, the native fan-in."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        topo = wan_topology([5, 5, 5], [[0.15, 31, 35], [31, 0.15, 11],
+                                        [35, 11, 0.15]])
+        groups = [[1, 2, 3, 4], [5, 6, 7, 8, 9], [10, 11, 12, 13, 14]]
+        cfgs = [vs.build_config("paxos", 15, topo=topo),
+                vs.build_config("pigpaxos", 15, topo=topo, pig=PigConfig(
+                    n_groups=3, groups=groups, prc=1))]
+        grid = [(0, 24, 1), (1, 24, 2), (1, 24, 3)]
+        return _compile_step(one_chip, cfgs, grid, "pallas")
+
+
 def test_group_step_compiles_with_native_fanin(group_step):
     assert "tpu_custom_call" in group_step
 
@@ -126,6 +142,27 @@ def test_stage_scopes_reach_the_op_name_metadata(request, step):
               for path in re.findall(r'op_name="([^"]*)"', text)
               for part in path.split("/")}
     assert set(STAGES[step]) <= scopes
+
+
+@pytest.mark.parametrize("step", sorted(STAGES) + ["wan_step"])
+def test_only_the_wan_step_has_a_regions_scope(request, step):
+    """The region lookups of the WAN branch carry ``regions`` inside the
+    stage that uses them, so a stage split still gives them to it; a
+    one-region (LAN) program and the EPaxos program carry none."""
+    text = request.getfixturevalue(step)
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    regional = {p for p in paths if "/regions/" in p}
+    if step != "wan_step":
+        assert not regional
+        return
+    scopes = {part.rsplit("(", 1)[-1].rstrip(")")
+              for path in paths for part in path.split("/")}
+    assert set(STAGES["group_step"]) <= scopes
+    for stage in ("relay_fanout", "relay_acks"):
+        assert any(f"/{stage}/regions/" in p and p.endswith("gather")
+                   for p in regional), stage
+    assert all("/relay_fanout/regions/" in p or "/relay_acks/regions/" in p
+               for p in regional)
 
 
 @pytest.mark.parametrize("step", sorted(STAGES))

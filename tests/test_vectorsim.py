@@ -16,11 +16,13 @@ DUR, WARM = 0.4, 0.2
 SEEDS = (1, 2)
 
 
-def _des_mean(protocol, n, pig, clients, topo=None, engine="fast"):
+def _des_mean(protocol, n, pig, clients, topo=None, engine="fast",
+              duration=DUR, warmup=WARM, **kw):
     t, m = [], []
     for s in SEEDS:
-        c = Cluster(protocol, n, pig=pig, seed=s, engine=engine, topo=topo)
-        st = c.measure(duration=DUR, warmup=WARM, clients=clients)
+        c = Cluster(protocol, n, pig=pig, seed=s, engine=engine, topo=topo,
+                    **kw)
+        st = c.measure(duration=duration, warmup=warmup, clients=clients)
         t.append(st.throughput)
         m.append(st.median_ms)
     return float(np.mean(t)), float(np.mean(m))
@@ -79,6 +81,28 @@ def test_wan_region_matrix_latency():
     bt, bm = _batch_mean(units, 20)
     assert 60.0 < bm < 70.0
     assert bt > 0
+
+
+FIG10_GROUPS = [[1, 2, 3, 4], [5, 6, 7, 8, 9], [10, 11, 12, 13, 14]]
+
+
+@pytest.mark.parametrize("protocol,pig", [
+    ("paxos", None),
+    ("pigpaxos", PigConfig(n_groups=3, groups=FIG10_GROUPS, prc=1))])
+def test_fig10_wan_matches_fast_engine_within_tolerance(protocol, pig):
+    """The paper's Fig. 10 deployment (15 nodes, 5 per region, the
+    leader's clients in its region), 0.5 + 1.0 s: the batch twin within
+    the gated [0.90, 1.10] window of the DES at 40 and 200 clients."""
+    topo = wan_topology([5, 5, 5], [[0.15, 31, 35], [31, 0.15, 11],
+                                    [35, 11, 0.15]])
+    window = {"duration": 1.0, "warmup": 0.5, "leader_timeout": 0.4}
+    units = vs.simulate_scenario(protocol, 15, pig=pig, topo=topo,
+                                 clients=(40, 200), seeds=SEEDS, **window)
+    for k in (40, 200):
+        dt, dm = _des_mean(protocol, 15, pig, k, topo=topo, **window)
+        bt, bm = _batch_mean(units, k)
+        assert bt == pytest.approx(dt, rel=0.10), (k, dt, bt)
+        assert bm == pytest.approx(dm, rel=0.10), (k, dm, bm)
 
 
 # ------------------------------------------------------------ Eq. 1-3
