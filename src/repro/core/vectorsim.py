@@ -123,6 +123,17 @@ def trace_counts() -> Dict[tuple, int]:
     return dict(_TRACE_COUNTS)
 
 
+# the group kernel's slot<->group lookups are one-hot selects up to one lane
+# tile of follower slots (see _group_cell), batched gathers above it
+_ONEHOT_MAX_F = 128
+# group-kernel programs traced per lookup form ("onehot" | "gather")
+_INDEX_COUNTS: Dict[str, int] = {}
+
+
+def index_counts() -> Dict[str, int]:
+    return dict(_INDEX_COUNTS)
+
+
 # ===================================================================== config
 @dataclasses.dataclass
 class SimConfig:
@@ -467,6 +478,40 @@ def _summarize(lat, t_fin, commit_t, active, ready, loadF, loadL, cell,
     return out
 
 
+def _take(x, oh, axis: int = 0):
+    """``jnp.take(x, idx, axis)`` for the one-hot mask ``oh = idx[...,
+    None] == arange(x.shape[axis])`` of in-range indices, as a select and
+    a max in place of a gather: exact for every non-NaN value, ±0 and ±inf
+    included (the one selected entry meets only the dtype's lowest value).
+    Serves both directions of the group kernel's layout: group -> slot
+    (``idx = grp``) and slot -> group (``idx = rel_idx``, ``gstart``)."""
+    with jax.named_scope("relay_index"):
+        axis %= x.ndim
+        k = oh.ndim - 1                        # idx.ndim
+        post = x.ndim - axis - 1
+        xm = jnp.moveaxis(x, axis, -1)
+        xm = xm.reshape(xm.shape[:axis] + (1,) * k + xm.shape[axis:])
+        m = oh.reshape((1,) * axis + oh.shape[:-1] + (1,) * post
+                       + oh.shape[-1:])
+        lowest = (-jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+                  else jnp.iinfo(x.dtype).min)
+        return jnp.where(m, xm, lowest).max(-1)
+
+
+def _lut(tab, a, b):
+    """``tab[a, b]`` for in-range integer arrays ``a``, ``b`` (broadcast
+    together) over a small table of static shape: one exact select per
+    entry in place of a 2-D gather."""
+    with jax.named_scope("relay_index"):
+        out = jnp.broadcast_to(tab[0, 0], jnp.broadcast_shapes(
+            jnp.shape(a), jnp.shape(b)))
+        for i in range(tab.shape[0]):
+            for j in range(tab.shape[1]):
+                if i or j:
+                    out = jnp.where((a == i) & (b == j), tab[i, j], out)
+        return out
+
+
 def _group_cell(cell, steps: int, kmax: int, breq: int,
                 faulty: bool = False, nb: int = 0, kernel: str = "lax",
                 obs: bool = False, read: bool = False):
@@ -502,7 +547,7 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
     from the leader.  When False the original constant-service expression
     is kept verbatim so existing compilations are unchanged.
 
-    Two throughput tricks keep the scan XLA-friendly:
+    Three throughput tricks keep the scan XLA-friendly:
 
     * followers live on a FLAT axis (slots packed group-contiguously;
       ``grp``/``pos``/``gstart`` index the segments), so a heterogeneous
@@ -513,7 +558,16 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
       all of them through the pipeline at once — their leader ingress is
       serialized exactly (Lindley chain with constant per-request work),
       follower backlog reads within the burst share the pre-step snapshot
-      (the same approximation the fluid model already makes across rounds).
+      (the same approximation the fluid model already makes across rounds);
+    * the relay stages' slot<->group lookups (group -> slot broadcasts,
+      the relay's slot, the segment starts, the WAN region table) are
+      exact one-hot selects (``_take``, ``_lut``) over the cell's layout,
+      its fixed masks built once before the scan: ``grp`` and ``gstart``
+      are per-cell data, so under ``vmap`` a gather is a batched dynamic
+      gather, which the TPU runs one element at a time.  The masks hold
+      F x G entries per burst row, so up to ``_ONEHOT_MAX_F`` slots (one
+      lane tile, the fan-in's unrolled width); wider grids keep the
+      gathers.  Both forms give the same bits.
     """
     f32 = jnp.float32
     grp = cell["grp"]                         # (F,) group of each slot
@@ -536,11 +590,37 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
     F = grp.shape[0]
     B = breq
 
+    onehot = F <= _ONEHOT_MAX_F
+    form = "onehot" if onehot else "gather"
+    _INDEX_COUNTS[form] = _INDEX_COUNTS.get(form, 0) + 1
+
+    def take(x, idx, axis=0, oh=None):
+        """``x`` at the in-range indices ``idx`` along ``axis``; ``oh`` is
+        idx's one-hot where it is fixed for the cell."""
+        if not onehot:
+            return jnp.take(x, idx, axis=axis, mode="clip")
+        if oh is None:
+            oh = idx[..., None] == jnp.arange(x.shape[axis])
+        return _take(x, oh, axis)
+
+    lut = _lut if onehot else (lambda tab, a, b: tab[a, b])
+
     szf = sizes.astype(f32)
     grp_mask = sizes > 0
     valid = jnp.arange(F) < cell["n_followers"]
     seg_first = jnp.broadcast_to(pos == 0, (B, F))
     grp_b = jnp.broadcast_to(grp, (B, F))
+    # the cell's fixed slot<->group indices: each slot's group, each
+    # group's first slot and its (thresh-2)-th reply's slot
+    g0 = jnp.clip(gstart, 0, F - 1)
+    t_idx = jnp.clip(gstart + thresh - 2, 0, F - 1)
+    oh_grp = oh_g0 = oh_t = None
+    if onehot:
+        oh_grp = grp[:, None] == jnp.arange(G)        # (F, G)
+        oh_g0 = g0[:, None] == jnp.arange(F)          # (G, F)
+        oh_t = t_idx[:, None] == jnp.arange(F)        # (G, F)
+    kg = jnp.maximum(thresh - 2, 0)                # each group's flush rank
+    kg_slot = take(kg, grp, 0, oh_grp)
     kk_r = jnp.arange(G, dtype=f32)
     kk_b = jnp.arange(B, dtype=f32)
     posf = pos.astype(f32)
@@ -636,7 +716,7 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
                 cnt = jnp.zeros(G, f32).at[grp].add(af)       # (G,) up members
                 k_sel = jnp.minimum(jnp.floor(u_rel * cnt[None, :]),
                                     jnp.maximum(cnt - 1.0, 0.0))   # (B, G)
-                k_slot = jnp.take_along_axis(k_sel, grp_b, axis=1)  # (B, F)
+                k_slot = take(k_sel, grp, 1, oh_grp)              # (B, F)
                 is_sel = (af > 0)[None, :] & (rank[None, :] == k_slot)
                 j_dyn = jnp.zeros((B, G), f32).at[:, grp].add(
                     jnp.where(is_sel, posf[None, :], 0.0))
@@ -647,6 +727,8 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
                                   jnp.floor(u_rel * szf).astype(jnp.int32))
             j_rel = jnp.clip(j_rel, 0, jnp.maximum(sizes - 1, 0))
             rel_idx = jnp.clip(gstart + j_rel, 0, F - 1)  # (B, G) flat slots
+            oh_rel = (rel_idx[..., None] == jnp.arange(F)) if onehot else None
+            j_slot = take(j_rel, grp, 1, oh_grp)            # (B, F)
 
         with jax.named_scope("relay_fanout"):
             # online rate estimate (EWMA of the L1 pacing interval) -> follower
@@ -680,32 +762,31 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
                 b_rp = b_pr = reg_lat[0, 0]
             else:
                 with jax.named_scope("regions"):
-                    reg_relay = regF[rel_idx]                 # (B, G)
-                    b_Lr = reg_lat[leader_reg, reg_relay]
+                    reg_relay = take(regF, rel_idx, 0, oh_rel)    # (B, G)
+                    b_Lr = lut(reg_lat, leader_reg, reg_relay)
                     # per-direction bases: one-way matrices may be asymmetric
-                    reg_relay_f = jnp.take_along_axis(reg_relay, grp_b,
-                                                      axis=1)
-                    b_rp = reg_lat[reg_relay_f, regF[None, :]]    # (B, F) out
-                    b_pr = reg_lat[regF[None, :], reg_relay_f]    # (B, F) back
+                    reg_relay_f = take(reg_relay, grp, 1, oh_grp)
+                    b_rp = lut(reg_lat, reg_relay_f, regF[None, :])  # out
+                    b_pr = lut(reg_lat, regF[None, :], reg_relay_f)  # back
             arr_rel = fan_done + b_Lr + e_Lr
             if faulty:
-                slow_rel = slowF[rel_idx]                     # (B, G)
-                arr_rel = defer(arr_rel + slowL + slow_rel, downF[rel_idx])
-            B_r = cpuF[rel_idx] - L1[:, None]
+                slow_rel = take(slowF, rel_idx, 0, oh_rel)    # (B, G)
+                down_rel = take(downF, rel_idx, 0, oh_rel)    # (B, G, W, 2)
+                arr_rel = defer(arr_rel + slowL + slow_rel, down_rel)
+            B_r = take(cpuF, rel_idx, 0, oh_rel) - L1[:, None]
             W_r = jnp.maximum(B_r + (rho - 1.0) * (arr_rel - L1[:, None]),
                               0.0) + md1
             h = arr_rel + W_r + c_fanout
-            is_relay = pos[None, :] == j_rel[:, grp]          # (B, F)
+            is_relay = pos[None, :] == j_slot                 # (B, F)
             peer_mask = valid[None, :] & ~is_relay
-            order = (pos[None, :] - (pos[None, :] > j_rel[:, grp])).astype(f32)
-            send_done = jnp.take_along_axis(h, grp_b, axis=1) \
-                + (order + 1.0) * c_rel
+            order = (pos[None, :] - (pos[None, :] > j_slot)).astype(f32)
+            send_done = take(h, grp, 1, oh_grp) + (order + 1.0) * c_rel
             arr_p = send_done + b_rp + e_rp
             if faulty:
                 # relay-out + peer-in slow extras; a down peer serves the
                 # relayed message after it recovers (its vote arrives late and
                 # simply sorts past the flush threshold if others cover it)
-                slow_rel_f = jnp.take_along_axis(slow_rel, grp_b, axis=1)
+                slow_rel_f = take(slow_rel, grp, 1, oh_grp)
                 arr_p = defer(arr_p + slow_rel_f + slowF[None, :], downF)
             W_p = jnp.maximum(cpuF[None, :] - L1[:, None]
                               + (rho - 1.0) * (arr_p - L1[:, None]), 0.0) + md1
@@ -713,9 +794,7 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
             arr_back = doneP + b_pr + e_pr
             if faulty:
                 # the returning reply queues at the relay once IT is back up
-                win_rel_f = jnp.take_along_axis(
-                    downF[rel_idx], grp_b[..., None, None],
-                    axis=1)                                   # (B,F,W,2)
+                win_rel_f = take(down_rel, grp, 1, oh_grp)    # (B,F,W,2)
                 arr_back = defer(arr_back + slow_rel_f + slowF[None, :],
                                  win_rel_f)
 
@@ -728,18 +807,15 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
             # each group's segment block in place with arrivals ascending, so
             # the value at flat slot f is group grp[f]'s pos[f]-th reply.
             relay_free0 = h + npeers.astype(f32)[None, :] * c_rel
-            kg = jnp.maximum(thresh - 2, 0)
+            B_rf = take(B_r, grp, 1, oh_grp)
             if kernel == "pallas":
                 # rank-counting Pallas kernel: emits each slot's capped segment
                 # max directly (the thresh-2 order statistic), no sort needed
                 from ..kernels import ops as _kops
                 m = _kops.seg_fanin(
-                    jnp.where(peer_mask, arr_back, jnp.inf),
-                    jnp.take_along_axis(B_r, grp_b, axis=1),
-                    grp, kg[grp], rho - 1.0, md1, c_repl, L1)
-                mg = jnp.take_along_axis(
-                    m, jnp.broadcast_to(jnp.clip(gstart, 0, F - 1), (B, G)),
-                    axis=1)
+                    jnp.where(peer_mask, arr_back, jnp.inf), B_rf,
+                    grp, kg_slot, rho - 1.0, md1, c_repl, L1)
+                mg = take(m, g0, 1, oh_g0)
                 done_g = (kg.astype(f32)[None, :] + 1.0) * c_repl \
                     + jnp.maximum(relay_free0, mg)
             else:
@@ -747,20 +823,17 @@ def _group_cell(cell, steps: int, kmax: int, breq: int,
                     (grp_b, jnp.where(peer_mask, arr_back, jnp.inf)),
                     num_keys=2)
                 w_fan = jnp.maximum(
-                    jnp.take_along_axis(B_r, grp_b, axis=1)
-                    + (rho - 1.0) * (arr_s - L1[:, None]), 0.0) + md1
+                    B_rf + (rho - 1.0) * (arr_s - L1[:, None]), 0.0) + md1
                 pref = seg_cummax(arr_s + w_fan - posf[None, :] * c_repl,
                                   seg_first, axis=1)
                 done_k = (posf[None, :] + 1.0) * c_repl + jnp.maximum(
-                    jnp.take_along_axis(relay_free0, grp_b, axis=1), pref)
-                t_idx = jnp.clip(gstart + thresh - 2, 0, F - 1)
-                done_g = jnp.take_along_axis(
-                    done_k, jnp.broadcast_to(t_idx, (B, G)), axis=1)
+                    take(relay_free0, grp, 1, oh_grp), pref)
+                done_g = take(done_k, t_idx, 1, oh_t)
             flush = jnp.where((thresh >= 2)[None, :], done_g, relay_free0)
             agg_sent = flush + c_agg
             if not lan:
                 with jax.named_scope("regions"):
-                    b_rL = reg_lat[reg_relay, leader_reg]     # (B, G)
+                    b_rL = lut(reg_lat, reg_relay, leader_reg)    # (B, G)
 
         with jax.named_scope("commit"):
             # leader FIFO over aggregates; commit at the quorum-completing one

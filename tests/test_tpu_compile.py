@@ -148,7 +148,8 @@ def test_stage_scopes_reach_the_op_name_metadata(request, step):
 def test_only_the_wan_step_has_a_regions_scope(request, step):
     """The region lookups of the WAN branch carry ``regions`` inside the
     stage that uses them, so a stage split still gives them to it; a
-    one-region (LAN) program and the EPaxos program carry none."""
+    one-region (LAN) program and the EPaxos program carry none.  They
+    are one-hot selects (``relay_index``), not gathers."""
     text = request.getfixturevalue(step)
     paths = re.findall(r'op_name="([^"]*)"', text)
     regional = {p for p in paths if "/regions/" in p}
@@ -159,10 +160,24 @@ def test_only_the_wan_step_has_a_regions_scope(request, step):
               for path in paths for part in path.split("/")}
     assert set(STAGES["group_step"]) <= scopes
     for stage in ("relay_fanout", "relay_acks"):
-        assert any(f"/{stage}/regions/" in p and p.endswith("gather")
+        assert any(f"/{stage}/regions/relay_index/" in p
                    for p in regional), stage
-    assert all("/relay_fanout/regions/" in p or "/relay_acks/regions/" in p
+    # a reduction's own computation names its path from the stage on
+    assert all(re.search(r"(^|/)(relay_fanout|relay_acks)/regions/", p)
                for p in regional)
+
+
+@pytest.mark.parametrize("step", ["group_step", "wan_step"])
+def test_relay_stages_hold_no_gather(request, step):
+    """At F <= 128 the relay stages look up the cell's slot<->group layout
+    and the region table by one-hot selects: no batched gather is left in
+    ``relay_pick``, ``relay_fanout`` or ``relay_acks``."""
+    text = request.getfixturevalue(step)
+    ops = re.findall(r'= \S+ (\w[\w-]*)\(.*op_name="([^"]*)"', text)
+    relay = [(op, p) for op, p in ops
+             if re.search(r"(^|/)(relay_pick|relay_fanout|relay_acks)/", p)]
+    assert any("/relay_index/" in p for _, p in relay)
+    assert not [p for op, p in relay if op == "gather"]
 
 
 @pytest.mark.parametrize("step", sorted(STAGES))

@@ -280,6 +280,166 @@ def test_resolve_kernel():
         vs._resolve_kernel("nope", "group")
 
 
+# ------------------------------------------- one-hot relay lookups
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
+
+
+def _specials(rng, shape, dtype):
+    """Random values of ``dtype`` with the extremes a select must keep:
+    ±0 and ±inf for floats, the int32 bounds for ints."""
+    if dtype == np.int32:
+        x = rng.integers(-1000, 1000, shape, dtype=np.int32)
+        pool = np.array([np.iinfo(np.int32).min, np.iinfo(np.int32).max, 0],
+                        np.int32)
+    else:
+        x = rng.standard_normal(shape).astype(np.float32)
+        pool = np.array([0.0, -0.0, np.inf, -np.inf], np.float32)
+    hit = rng.random(shape) < 0.3
+    x[hit] = rng.choice(pool, int(hit.sum()))
+    return x
+
+
+LAYOUTS = {
+    "paxos24": [("paxos", 25, None)],
+    "pig-r2": [("pigpaxos", 25, PigConfig(n_groups=2, prc=1))],
+    "pig-r3": [("pigpaxos", 25, PigConfig(n_groups=3, prc=1))],
+    "pig-r5": [("pigpaxos", 25, PigConfig(n_groups=5, prc=1))],
+    "wan-4-5-5": [("pigpaxos", 15, PigConfig(n_groups=3, groups=FIG10_GROUPS,
+                                             prc=1))],
+    # padded to the batch's fmax = rmax = 24: gstart = F on R=3's padded
+    # groups, padded slots (grp = rmax - 1) behind N=9's 8 real ones
+    "padded": [("pigpaxos", 25, PigConfig(n_groups=3, prc=1)),
+               ("pigpaxos", 9, PigConfig(n_groups=2, prc=1)),
+               ("paxos", 25, None)],
+}
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int32])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_onehot_lookups_match_gathers_bit_for_bit(layout, dtype):
+    """``_take`` (group -> slot broadcasts, slot -> group picks) and
+    ``_lut`` (the region table) give the gathers' bits on each cell's
+    layout, under ``vmap`` over cells as the scan runs them."""
+    import jax
+    import jax.numpy as jnp
+
+    cfgs = [vs.build_config(p, n, pig=pig) for p, n, pig in LAYOUTS[layout]]
+    batch, _, _ = vs._stack_cells(cfgs, [(i, 4, 0) for i in range(len(cfgs))],
+                                  0.1, 0.05)
+    grp, gstart, thresh = batch["grp"], batch["gstart"], batch["thresh"]
+    C, F = grp.shape
+    G = gstart.shape[1]
+    B, W = 8, 2
+    if layout == "padded":
+        assert (gstart == F).any() and (grp[1, 8:] == G - 1).all()
+    rng = np.random.default_rng(len(layout))
+    j = rng.integers(0, np.maximum(batch["sizes"], 1)[:, None], (C, B, G))
+    rel_idx = np.clip(gstart[:, None] + j, 0, F - 1)           # (C, B, G)
+    g0 = np.clip(gstart, 0, F - 1)
+    t_idx = np.clip(gstart + thresh - 2, 0, F - 1)
+    xg = _specials(rng, (C, B, G), dtype)
+    xf = _specials(rng, (C, F), dtype)
+    xbf = _specials(rng, (C, B, F), dtype)
+    xw = _specials(rng, (C, F, W, 2), dtype)
+    xgw = _specials(rng, (C, B, G, W, 2), dtype)
+
+    def onehot(i, n):
+        return i[..., None] == jnp.arange(n)
+
+    cases = {
+        "group->slot": (jax.vmap(lambda x, g: vs._take(x, onehot(g, G), 1))(
+            xg, grp), np.take_along_axis(xg, np.broadcast_to(
+                grp[:, None], (C, B, F)), axis=2)),
+        "group->slot, trailing": (
+            jax.vmap(lambda x, g: vs._take(x, onehot(g, G), 1))(xgw, grp),
+            np.stack([xgw[c][:, grp[c]] for c in range(C)])),
+        "relay slot": (
+            jax.vmap(lambda x, r: vs._take(x, onehot(r, F)))(xf, rel_idx),
+            np.stack([xf[c][rel_idx[c]] for c in range(C)])),
+        "relay slot, trailing": (
+            jax.vmap(lambda x, r: vs._take(x, onehot(r, F)))(xw, rel_idx),
+            np.stack([xw[c][rel_idx[c]] for c in range(C)])),
+    }
+    for name, idx in (("segment start", g0), ("flush slot", t_idx)):
+        cases[name] = (
+            jax.vmap(lambda x, i: vs._take(x, onehot(i, F), 1))(xbf, idx),
+            np.take_along_axis(xbf, np.broadcast_to(idx[:, None], (C, B, G)),
+                               axis=2))
+    for name, (got, want) in cases.items():
+        assert got.shape == want.shape, name
+        assert np.array_equal(_bits(got), _bits(want)), name
+
+    tab = _specials(rng, (3, 3), dtype)
+    a = rng.integers(0, 3, (B, F))
+    b = rng.integers(0, 3, (1, F))
+    for aa in (a, np.int32(2)):
+        got = vs._lut(jnp.asarray(tab), jnp.asarray(aa), jnp.asarray(b))
+        assert np.array_equal(_bits(got), _bits(tab[aa, b]))
+
+
+def _lookup_grids():
+    from repro.core import WorkloadConfig
+    from repro.faults import crash_window, slow_window
+
+    topo = wan_topology([5, 5, 5], [[0.15, 31, 35], [31, 0.15, 11],
+                                    [35, 11, 0.15]])
+    fig8 = [vs.build_config("pigpaxos", 13, pig=PigConfig(n_groups=3, prc=1)),
+            vs.build_config("paxos", 13)]
+    wan = [vs.build_config("paxos", 15, topo=topo),
+           vs.build_config("pigpaxos", 15, topo=topo, pig=PigConfig(
+               n_groups=3, groups=FIG10_GROUPS, prc=1))]
+    masks = (crash_window(5, 0.02, 0.08)
+             + slow_window(3, extra_latency=2e-3)).to_masks(13, 0.2)
+    faults = [vs.build_config("pigpaxos", 13, pig=PigConfig(n_groups=3),
+                              masks=masks)]
+    reads = [vs.build_config("pigpaxos", 13, pig=PigConfig(n_groups=3, prc=1),
+                             workload=WorkloadConfig(read_ratio=0.5,
+                                                     read_path="lease"))]
+    two = [(ci, k, s) for ci in range(2) for k in (4, 8) for s in (0, 1)]
+    one = [(0, 8, s) for s in range(3)]
+    return {"fig8-lax": (fig8, two, 0.1, 0.05, "lax"),
+            "fig8-pallas": (fig8, two, 0.1, 0.05, "pallas"),
+            "wan": (wan, [(ci, k, s) for ci in range(2) for k in (10, 40)
+                          for s in (0, 1)], 0.3, 0.1, "lax"),
+            "faults": (faults, one, 0.2, 0.0, "lax"),
+            "reads": (reads, one, 0.1, 0.05, "lax")}
+
+
+@pytest.mark.parametrize("case", ["fig8-lax", "fig8-pallas", "wan",
+                                  "faults", "reads"])
+def test_onehot_grid_equals_gather_grid(case, monkeypatch):
+    """Every output of a grid on the one-hot lookups equals the gathers'
+    (the path wide grids take, forced here by a zero width boundary), bit
+    for bit, and the trace-time counter names the path each program
+    took."""
+    import jax
+
+    cfgs, grid, dur, warm, kernel = _lookup_grids()[case]
+
+    def run():
+        before = vs.index_counts()
+        out = vs.simulate_grid(cfgs, grid, dur, warm, kernel=kernel)
+        after = vs.index_counts()
+        return out, {k: v - before.get(k, 0) for k, v in after.items()
+                     if v != before.get(k, 0)}
+
+    jax.clear_caches()
+    try:
+        onehot, traced = run()
+        assert set(traced) == {"onehot"}, traced
+        monkeypatch.setattr(vs, "_ONEHOT_MAX_F", 0)
+        jax.clear_caches()
+        gather, traced = run()
+        assert set(traced) == {"gather"}, traced
+    finally:
+        jax.clear_caches()
+    assert sorted(onehot) == sorted(gather)
+    for key in onehot:
+        assert np.array_equal(_bits(onehot[key]), _bits(gather[key])), key
+
+
 # ------------------------------------------------------ runner / spec
 def test_runner_batch_backend_artifact():
     sc = Scenario(name="t/batch", protocol="pigpaxos", n=9,
