@@ -8,7 +8,7 @@ Three measurements, written to BENCH_vectorsim.json at the repo root:
   cold and warm wall clock.
 * ``sharded`` — the same grid through ``vectorsim.simulate_grid_sharded``
   (device-sharded chunked dispatch, bit-identical results): per-chunk
-  walls, cells/s, device count, kernel flag.
+  walls, cells/s, device count, the fan-in taken.
 * ``des``     — the same grid on ``Cluster(engine="fast")``: a stratified
   sample of units (every (config, clients) point, subset of seeds) is
   measured serially AND through a real ``multiprocessing`` pool at

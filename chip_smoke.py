@@ -11,9 +11,10 @@ One chip, in order:
    oracle at B in {4, 8} and F in {24, 1024}, compiled natively;
 2. the ``vectorsim_bench`` fig8 grid (N=25, Paxos + rotating PigPaxos
    R in {2, 3, 5} at PRC=1, clients {20, 60, 120} x 32 seeds = 384
-   cells) through ``simulate_grid``: ``kernel="auto"`` must resolve to
-   the native Pallas kernel (its compiled step holds a
-   ``tpu_custom_call``) and agree with ``kernel="lax"``;
+   cells) through ``simulate_grid``: the platform rule must pick the
+   native Pallas fan-in (its compiled step holds a ``tpu_custom_call``),
+   which must agree with the lax path, forced through the grid entries'
+   ``_fanin`` hook;
 3. ``experiments.run_scenarios`` on the gated DES <-> batch twins and
    the N=1025 scale cell, judged by the regression gate's fidelity
    windows and audit check (the DES is the independent reference).
@@ -141,24 +142,29 @@ def check_grid(tag: str) -> None:
     from benchmarks.vectorsim_bench import DUR, WARM
     from repro.core import vectorsim as vs
 
-    kernel = vs._resolve_kernel("auto", "group")
-    require(kernel == "pallas", f'kernel="auto" resolved to {kernel!r}')
+    kernel = vs._fanin("group")
+    require(kernel == "pallas", f"the platform rule picked {kernel!r}")
     sims, grid = fig8_grid(GRID_SEEDS)
     walls = {}
     outs = {}
-    for name, kw in (("auto", {}), ("lax", {"kernel": "lax"})):
-        t0 = time.perf_counter()
-        outs[name] = vs.simulate_grid(sims, grid, DUR, WARM, **kw)
-        walls[name + "_cold"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        outs[name] = vs.simulate_grid(sims, grid, DUR, WARM, **kw)
-        walls[name + "_warm"] = time.perf_counter() - t0
+    platform = vs._fanin
+    for name, fanin in (("platform", platform), ("lax", lambda kind: "lax")):
+        vs._fanin = fanin
+        try:
+            t0 = time.perf_counter()
+            outs[name] = vs.simulate_grid(sims, grid, DUR, WARM)
+            walls[name + "_cold"] = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            outs[name] = vs.simulate_grid(sims, grid, DUR, WARM)
+            walls[name + "_warm"] = time.perf_counter() - t0
+        finally:
+            vs._fanin = platform
     for name, out in outs.items():
         require(not out["exhausted"].any(), f"{name}: exhausted cells")
         tput = out["throughput"]
         require(np.isfinite(tput).all() and (tput > 0).all(),
                 f"{name}: throughput not finite and positive")
-    pal, lax = outs["auto"], outs["lax"]
+    pal, lax = outs["platform"], outs["lax"]
     np.testing.assert_allclose(pal["throughput"], lax["throughput"],
                                rtol=1e-5)
     for key in ("median_s", "p99_s"):
@@ -168,8 +174,8 @@ def check_grid(tag: str) -> None:
                                                    "p99_s")], axis=0).sum())
     budget = int(pal["steps"].max())
     log(f"[grid] {tag} {len(grid)} cells, request budget {budget} per cell: "
-        f"auto->pallas cold={walls['auto_cold']}s "
-        f"warm={walls['auto_warm']}s; lax cold={walls['lax_cold']}s "
+        f"platform->pallas cold={walls['platform_cold']}s "
+        f"warm={walls['platform_warm']}s; lax cold={walls['lax_cold']}s "
         f"warm={walls['lax_warm']}s (host wall, compile in cold)")
     log(f"[grid] {tag} pallas == lax: throughput rtol=1e-5, median/p99 "
         f"rtol=1e-4 ({exact}/{len(grid)} cells bit-equal); mean throughput "

@@ -92,7 +92,7 @@ import dataclasses
 import functools
 import time
 import warnings
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1116,17 +1116,17 @@ def _epaxos_cell(cell, steps: int, kmax: int, nb: int = 0):
 
 
 # ================================================================== batching
-def _resolve_kernel(kernel: str, kind: str = "group") -> str:
-    """"auto" -> the native fan-in for the current backend ("pallas" on
-    TPU, the XLA "lax" path elsewhere).  The epaxos kernel has no grouped
-    fan-in, so it always normalizes to "lax" (avoids spurious retraces)."""
-    if kind != "group":
-        return "lax"
-    if kernel == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "lax"
-    if kernel not in ("lax", "pallas"):
-        raise ValueError(f"kernel must be auto|lax|pallas, got {kernel!r}")
-    return kernel
+def _resolve_kernel(kind: str) -> str:
+    """The platform rule for the reply fan-in: the Pallas kernel for the
+    group kernel on a TPU backend, the XLA "lax" path otherwise (the
+    epaxos kernel has no grouped fan-in)."""
+    return ("pallas" if kind == "group" and jax.default_backend() == "tpu"
+            else "lax")
+
+
+# the fan-in rule both grid entries read; tests swap it to force one path
+# (the lax path is the Pallas kernel's oracle)
+_fanin = _resolve_kernel
 
 
 def _cells_fn(batch, steps: int, kmax: int, kind: str, breq: int,
@@ -1308,8 +1308,6 @@ def _stack_cells(configs: Sequence[SimConfig], grid, duration: float,
 
 def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
                   warmup: float, steps: Optional[int] = None,
-                  timeline: bool = False,
-                  kernel: str = "auto",
                   obs: bool = False) -> Dict[str, np.ndarray]:
     """Run every (config_idx, clients, seed) grid point in ONE jitted call.
 
@@ -1320,65 +1318,89 @@ def simulate_grid(configs: Sequence[SimConfig], grid, duration: float,
     exhausted subset re-runs with a doubled budget — finished cells keep
     their first-pass results, which are bit-identical to what a full-grid
     retry would produce (extra scan steps past the stop time are no-ops).
-    ``out["steps"]`` records each cell's final budget.
-
-    ``timeline=True`` (implied by fault-mask configs) adds per-cell
-    completion timelines (``_TL_BUCKET`` buckets).
+    ``out["steps"]`` records each cell's final budget.  Fault-mask configs
+    add per-cell completion timelines (``_TL_BUCKET`` buckets).
 
     ``obs=True`` (group kernel only) adds the per-cell leader-backlog
     series (``leader_backlog_s`` / ``leader_backlog_n``; see
     ``_group_cell``) on the same buckets.
-
-    ``kernel`` selects the group fan-in implementation ("auto" | "lax" |
-    "pallas"; see ``_group_cell``) — "auto" picks the Pallas kernel on TPU
-    and the XLA sort path elsewhere.
     """
     with TraceAnnotation("vectorsim.grid") as span:
-        batch, kind, kmax = _stack_cells(configs, grid, duration, warmup)
-        kernel = _resolve_kernel(kernel, kind)
-        if obs and kind != "group":
-            raise ValueError("obs timelines are group-kernel only — the "
-                             "epaxos kernel has no single-leader FIFO to "
-                             "observe")
-        faulty = any(c.down is not None or c.slow is not None
-                     for c in configs)
-        read = any(c.read_ratio > 0.0 for c in configs)
-        nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
-              if (faulty or timeline or obs) else 0)
-        if steps is None:
-            # requests are only issued inside [0, stop); the rate bound is
-            # optimistic, and the exhausted-retry loop below is the safety
-            # net
-            with TraceAnnotation("vectorsim.budget"):
-                rate = max(_estimate_rate(configs[ci], k)
-                           for ci, k, _ in grid)
-            steps = int(rate * (warmup + duration) * 1.15) + kmax + 64
-        steps = min(steps, _MAX_STEPS)
-        # the group kernel pops `breq` requests per scan step
-        breq = min(8, kmax) if kind == "group" else 1
-
-        def run(b, scan_steps):
-            return _run_cells(b, scan_steps, kmax, kind, breq, faulty, nb,
-                              kernel, obs, read)
-
-        out = _pass(run, batch, steps, breq)
-        steps_arr = np.full(len(grid), steps, np.int32)
-        if out["exhausted"].any():
-            out = {k: np.array(v) for k, v in out.items()}  # writable
-        p = 0
-        while out["exhausted"].any() and steps < _MAX_STEPS:
-            p += 1
-            steps = min(steps * 2, _MAX_STEPS)
-            idx = np.nonzero(out["exhausted"])[0]
-            with TraceAnnotation("vectorsim.retry"):
-                sub = {k: v[idx] for k, v in batch.items()}
-                for k, v in _pass(run, sub, steps, breq, retry=True).items():
-                    out[k][idx] = v
-            steps_arr[idx] = steps
-        out["steps"] = steps_arr
-        span.set_metadata(passes=p + 1,
+        batch, _, kmax = _stack_cells(configs, grid, duration, warmup)
+        steps, plan = _plan(configs, grid, duration, warmup, steps, kmax,
+                            obs)
+        out, passes = _passes(lambda b, s: _run_cells(b, s, *plan), batch,
+                              steps, plan.breq, len(grid), 1)
+        span.set_metadata(passes=passes,
                           **_region_count(batch["reg_lat"].shape[-1]))
     return out
+
+
+class _Plan(NamedTuple):
+    """A grid call's static program arguments, in ``_run_cells`` order."""
+    kmax: int
+    kind: str
+    breq: int
+    faulty: bool
+    nb: int
+    kernel: str
+    obs: bool
+    read: bool
+
+
+def _plan(configs: Sequence[SimConfig], grid, duration: float,
+          warmup: float, steps: Optional[int], kmax: int,
+          obs: bool = False) -> Tuple[int, _Plan]:
+    """The decisions both grid entries share: the first request budget
+    (the grid max of the optimistic rate bound unless ``steps`` is given,
+    capped at ``_MAX_STEPS``) and the program's static arguments (mode
+    flags, burst, fan-in)."""
+    kind = configs[0].kind
+    if obs and kind != "group":
+        raise ValueError("obs timelines are group-kernel only — the "
+                         "epaxos kernel has no single-leader FIFO to "
+                         "observe")
+    faulty = any(c.down is not None or c.slow is not None for c in configs)
+    read = any(c.read_ratio > 0.0 for c in configs)
+    nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
+          if (faulty or obs) else 0)
+    if steps is None:
+        # requests are only issued inside [0, stop); the rate bound is
+        # optimistic, and the exhausted-retry loop is the safety net
+        with TraceAnnotation("vectorsim.budget"):
+            rate = max(_estimate_rate(configs[ci], k) for ci, k, _ in grid)
+        steps = int(rate * (warmup + duration) * 1.15) + kmax + 64
+    # the group kernel pops `breq` requests per scan step
+    breq = min(8, kmax) if kind == "group" else 1
+    return min(steps, _MAX_STEPS), _Plan(kmax, kind, breq, faulty, nb,
+                                         _fanin(kind), obs, read)
+
+
+def _passes(run, batch, steps: int, breq: int, real: int,
+            pad: int) -> Tuple[Dict[str, np.ndarray], int]:
+    """The exhausted-retry loop: one pass over ``batch``, then ONLY the
+    exhausted cells re-run at a doubled budget until none of the first
+    ``real`` cells is exhausted or the budget reaches ``_MAX_STEPS``.  A
+    retry subset is padded to a multiple of ``pad`` (the sharded runner's
+    device count) by repeating its cells.  Returns the outputs, with each
+    cell's final budget under ``"steps"``, and the number of passes."""
+    out = _pass(run, batch, steps, breq)
+    steps_arr = np.full(len(batch["key"]), steps, np.int32)
+    if out["exhausted"].any():
+        out = {k: np.array(v) for k, v in out.items()}  # writable
+    passes = 1
+    while out["exhausted"][:real].any() and steps < _MAX_STEPS:
+        passes += 1
+        steps = min(steps * 2, _MAX_STEPS)
+        idx = np.nonzero(out["exhausted"])[0]
+        with TraceAnnotation("vectorsim.retry"):
+            ridx = np.resize(idx, -(-len(idx) // pad) * pad)
+            sub = {k: v[ridx] for k, v in batch.items()}
+            for k, v in _pass(run, sub, steps, breq, retry=True).items():
+                out[k][idx] = v[:len(idx)]
+        steps_arr[idx] = steps
+    out["steps"] = steps_arr
+    return out, passes
 
 
 def _region_count(nreg: int) -> Dict[str, int]:
@@ -1414,8 +1436,8 @@ _SHARD_CACHE: Dict[tuple, object] = {}
 
 
 def _run_cells_sharded(batch, steps: int, kmax: int, kind: str, breq: int,
-                       faulty: bool, nb: int, kernel: str,
-                       devices, read: bool = False):
+                       faulty: bool, nb: int, kernel: str, obs: bool,
+                       read: bool, devices):
     """One chunk through the device-sharded runner.  The cell axis (every
     leaf's leading axis) is split evenly across ``devices`` — cell count
     must be a multiple of the device count.  Inputs are DONATED: chunked
@@ -1424,12 +1446,12 @@ def _run_cells_sharded(batch, steps: int, kmax: int, kind: str, breq: int,
     D = len(devices)
     shapes = tuple((k,) + tuple(v.shape) + (str(np.asarray(v).dtype),)
                    for k, v in sorted(batch.items()))
-    sig = (kind, steps, kmax, breq, faulty, nb, kernel, D, read) + shapes
+    sig = (kind, steps, kmax, breq, faulty, nb, kernel, obs, read, D) + shapes
     fn = _SHARD_CACHE.get(sig)
     if fn is None:
         def body(b):
             return _cells_fn(b, steps, kmax, kind, breq, faulty, nb,
-                             kernel, read=read)
+                             kernel, obs, read)
         mesh = Mesh(np.asarray(devices), ("cells",))
         fn = jax.jit(jax.shard_map(body, mesh=mesh,
                                    in_specs=PartitionSpec("cells"),
@@ -1447,9 +1469,7 @@ def _run_cells_sharded(batch, steps: int, kmax: int, kind: str, breq: int,
 
 def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
                           duration: float, warmup: float, *,
-                          steps: Optional[int] = None,
-                          timeline: bool = False, kernel: str = "auto",
-                          chunk: int = 4096,
+                          steps: Optional[int] = None, chunk: int = 4096,
                           devices=None) -> Dict[str, np.ndarray]:
     """``simulate_grid`` scaled out: the cell grid is partitioned across
     devices (``jax.shard_map``) and dispatched in fixed-size chunks whose
@@ -1464,38 +1484,18 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
     single-device ``simulate_grid`` (cells are independent vmap lanes).
 
     Returns the ``simulate_grid`` dict plus ``out["sharding"]``: device
-    count, kernel, chunk size, and per-chunk {cells, wall_s, steps} — the
-    stream the megagrid study and the bench schema consume.
+    count, fan-in taken, chunk size, and per-chunk {cells, wall_s, steps}
+    — the stream the megagrid study and the bench schema consume.
     """
     with TraceAnnotation("vectorsim.grid") as span:
         devices = list(devices if devices is not None else jax.devices())
         D = len(devices)
         chunk = max(chunk - chunk % D, D)
-        kind = configs[0].kind
-        kernel = _resolve_kernel(kernel, kind)
         spec = _pad_spec(configs, grid)
-        faulty = any(c.down is not None or c.slow is not None
-                     for c in configs)
-        read = any(c.read_ratio > 0.0 for c in configs)
-        nb = (int(np.ceil((warmup + duration + _DRAIN_S) / _TL_BUCKET)) + 1
-              if (faulty or timeline) else 0)
-        if steps is None:
-            with TraceAnnotation("vectorsim.budget"):
-                rate = max(_estimate_rate(configs[ci], k)
-                           for ci, k, _ in grid)
-            steps = int(rate * (warmup + duration) * 1.15) \
-                + spec["kmax"] + 64
-        steps0 = min(steps, _MAX_STEPS)
-        breq = min(8, spec["kmax"]) if kind == "group" else 1
-
-        def run(b, scan_steps):
-            return _run_cells_sharded(b, scan_steps, spec["kmax"], kind,
-                                      breq, faulty, nb, kernel, devices,
-                                      read)
-
+        steps, plan = _plan(configs, grid, duration, warmup, steps,
+                            spec["kmax"])
         n_cells = len(grid)
         out: Dict[str, np.ndarray] = {}
-        steps_arr = np.empty(n_cells, np.int32)
         meta = []
         passes = 0
         for lo in range(0, n_cells, chunk):
@@ -1506,35 +1506,18 @@ def simulate_grid_sharded(configs: Sequence[SimConfig], grid,
                 batch, _, _ = _stack_cells(configs, part, duration, warmup,
                                            pad_to=spec)
                 t0 = time.perf_counter()
-                steps_c, p = steps0, 0
-                cout = _pass(run, batch, steps_c, breq)
-                if cout["exhausted"].any():
-                    cout = {k: np.array(v) for k, v in cout.items()}
-                csteps = np.full(chunk, steps_c, np.int32)
-                while cout["exhausted"][:real].any() and steps_c < _MAX_STEPS:
-                    p += 1
-                    steps_c = min(steps_c * 2, _MAX_STEPS)
-                    idx = np.nonzero(cout["exhausted"])[0]
-                    with TraceAnnotation("vectorsim.retry"):
-                        # the exhausted subset, padded back to a device
-                        # multiple
-                        ridx = np.resize(idx, -(-len(idx) // D) * D)
-                        sub = {k: v[ridx] for k, v in batch.items()}
-                        for k, v in _pass(run, sub, steps_c, breq,
-                                          retry=True).items():
-                            cout[k][idx] = v[:len(idx)]
-                    csteps[idx] = steps_c
+                cout, p = _passes(
+                    lambda b, s: _run_cells_sharded(b, s, *plan, devices),
+                    batch, steps, plan.breq, real, D)
                 wall = time.perf_counter() - t0
-            passes += p + 1
+            passes += p
             for k, v in cout.items():
                 if k not in out:
                     out[k] = np.empty((n_cells,) + v.shape[1:], v.dtype)
                 out[k][lo:lo + real] = v[:real]
-            steps_arr[lo:lo + real] = csteps[:real]
             meta.append({"cells": real, "wall_s": wall,
-                         "steps": int(csteps[:real].max())})
-        out["steps"] = steps_arr
-        out["sharding"] = {"devices": D, "kernel": kernel,
+                         "steps": int(cout["steps"][:real].max())})
+        out["sharding"] = {"devices": D, "kernel": plan.kernel,
                            "chunk": chunk, "chunks": meta}
         span.set_metadata(passes=passes, **_region_count(spec["nreg"]))
     return out
@@ -1545,7 +1528,7 @@ def simulate_scenario(protocol: str, n: int, *, pig=None, topo=None,
                       seeds: Sequence[int] = (0,), duration: float = 0.6,
                       warmup: float = 0.3, leader_timeout: float = 50e-3,
                       masks: Optional[Dict[str, np.ndarray]] = None,
-                      kernel: str = "auto", batch_m: int = 1,
+                      batch_m: int = 1,
                       obs: bool = False) -> List[dict]:
     """One scenario's full clients x seeds grid in one compiled call.
 
@@ -1591,8 +1574,7 @@ def simulate_scenario(protocol: str, n: int, *, pig=None, topo=None,
                                  f"batch_m={m}: one kernel lane carries a "
                                  f"whole batch of {m} clients")
     grid = [(0, int(k) // m, int(s)) for k in clients for s in seeds]
-    out = simulate_grid([cfg], grid, duration, warmup, kernel=kernel,
-                        obs=obs)
+    out = simulate_grid([cfg], grid, duration, warmup, obs=obs)
     # mean reply rank correction (seconds); 0 when unbatched
     lat_adj = 0.0 if m == 1 else (m - 1) / 2.0 * (cfg.costs["c_replycl"] / m)
     units = []

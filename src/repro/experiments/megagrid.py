@@ -22,7 +22,8 @@ then each bucket streams through the device-sharded runner chunk by chunk
 (donated inputs, bounded device memory).  Results aggregate into ONE
 ``repro-experiments/v1`` artifact — per-point curve entries under the
 ``megagrid`` family plus a ``megagrid`` section with per-chunk walls,
-cells/s, device count and kernel flag.
+cells/s, device count and the fan-in the platform picked (the Pallas
+kernel on a TPU, the lax path elsewhere).
 
 CLI:  ``python -m repro.experiments.megagrid --cells 1000000 --out FILE``
 (``--preset smoke`` is the CI slice).  On GPU/TPU hosts the same command
@@ -140,15 +141,13 @@ def _bucket_key(pt: dict, k: int) -> tuple:
 
 # ------------------------------------------------------------------ the run
 def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
-                 chunk: int = 4096, kernel: str = "auto",
-                 duration: float = 0.1,
+                 chunk: int = 4096, duration: float = 0.1,
                  warmup: float = 0.05, progress=print) -> dict:
     """Run the cross-product study at >= ``cells`` total grid cells and
     return the ``repro-experiments/v1`` artifact (see module docstring).
 
     Memory is bounded by ``chunk`` (sharded dispatch donates each chunk's
-    buffers); compile cost is one trace per bucket.  ``kernel`` passes
-    through to ``simulate_grid_sharded``.
+    buffers); compile cost is one trace per bucket.
     """
     import jax
 
@@ -179,7 +178,7 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             spans.append((pi, k, s0, len(grid)))
         t0 = time.perf_counter()
         out = vs.simulate_grid_sharded(cfgs, grid, duration, warmup,
-                                       chunk=chunk, kernel=kernel)
+                                       chunk=chunk)
         wall = time.perf_counter() - t0
         for pi, k, lo, hi in spans:
             tput = out["throughput"][lo:hi]
@@ -251,7 +250,7 @@ def run_megagrid(cells: int = 1_000_000, *, axes: Dict = FULL_AXES,
             "device_count": int(jax.device_count()),
             "backend": jax.default_backend(),
             "device_kind": jax.devices()[0].device_kind,
-            "kernel": vs._resolve_kernel(kernel, "group"),
+            "kernel": vs._fanin("group"),
             "chunk": chunk,
             "duration_s": duration, "warmup_s": warmup,
             "buckets": bmeta,
@@ -265,8 +264,6 @@ def main(argv=None) -> int:
     ap.add_argument("--cells", type=int, default=1_000_000)
     ap.add_argument("--preset", choices=("full", "smoke"), default="full")
     ap.add_argument("--chunk", type=int, default=4096)
-    ap.add_argument("--kernel", default="auto",
-                    choices=("auto", "lax", "pallas"))
     ap.add_argument("--duration", type=float, default=0.1)
     ap.add_argument("--warmup", type=float, default=0.05)
     ap.add_argument("--out", default="megagrid.json")
@@ -274,7 +271,7 @@ def main(argv=None) -> int:
     compile_cache.enable()
     axes = SMOKE_AXES if args.preset == "smoke" else FULL_AXES
     art = run_megagrid(args.cells, axes=axes, chunk=args.chunk,
-                       kernel=args.kernel, duration=args.duration,
+                       duration=args.duration,
                        warmup=args.warmup)
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1, sort_keys=True)

@@ -3,7 +3,9 @@
 Run in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=4
 so the main pytest process keeps its single-device view.  Asserts the
 sharded grid runner (``jax.shard_map``, chunked and not) is bit-identical
-to the single-call ``simulate_grid`` on the same cells.
+to the single-call ``simulate_grid`` on the same cells, on the platform's
+fan-in and on the Pallas kernel (interpret mode here), forced through the
+grid entries' ``_fanin`` hook.
 """
 import os
 
@@ -43,6 +45,18 @@ def main() -> None:
     np.testing.assert_array_equal(np.asarray(ewant["throughput"]),
                                   egot["throughput"])
     print("OK epaxos")
+
+    # the Pallas fan-in, forced through the hook: ragged chunks with
+    # padding, sharded == one device
+    vs._fanin = lambda kind: "pallas"
+    pgrid = grid[:20]
+    pwant = vs.simulate_grid(cfgs, pgrid, 0.1, 0.05)
+    pgot = vs.simulate_grid_sharded(cfgs, pgrid, 0.1, 0.05, chunk=8)
+    assert pgot["sharding"]["kernel"] == "pallas", pgot["sharding"]
+    for key in ("throughput", "median_s", "p99_s", "committed"):
+        np.testing.assert_array_equal(np.asarray(pwant[key]), pgot[key],
+                                      err_msg=f"pallas key={key}")
+    print(f"OK pallas ({len(pgot['sharding']['chunks'])} chunks)")
     print("OK all")
 
 

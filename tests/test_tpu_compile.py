@@ -87,7 +87,7 @@ def test_seg_fanin_compiles_native(one_chip, B, F):
 @pytest.fixture(scope="module")
 def group_step(one_chip):
     """One whole PigPaxos N=25 scan step program with the Pallas fan-in
-    lowered natively (the path ``kernel="auto"`` takes on a TPU)."""
+    lowered natively (the fan-in the platform picks on a TPU)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_interpret", lambda: False)
         cfgs = [vs.build_config("pigpaxos", 25, pig=PigConfig(n_groups=3,

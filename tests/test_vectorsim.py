@@ -237,47 +237,71 @@ def test_sharded_grid_multidevice_subprocess():
 
 
 # ------------------------------------------------- pallas fan-in kernel
-def test_kernel_pallas_matches_lax_path():
+def _use_fanin(monkeypatch, kernel):
+    """Force the grid entries' fan-in through their hook."""
+    monkeypatch.setattr(vs, "_fanin", lambda kind: kernel)
+
+
+def test_kernel_pallas_matches_lax_path(monkeypatch):
     """The Pallas segmented fan-in kernel is a drop-in for the sort-based
     lax path: same grid, same tolerances as the DES cross-check."""
     pig = PigConfig(n_groups=3, prc=1)
     kw = dict(pig=pig, clients=(10, 20), seeds=(0, 1),
               duration=0.15, warmup=0.05)
-    lax_u = vs.simulate_scenario("pigpaxos", 25, kernel="lax", **kw)
-    pal_u = vs.simulate_scenario("pigpaxos", 25, kernel="pallas", **kw)
+    _use_fanin(monkeypatch, "lax")
+    lax_u = vs.simulate_scenario("pigpaxos", 25, **kw)
+    _use_fanin(monkeypatch, "pallas")
+    pal_u = vs.simulate_scenario("pigpaxos", 25, **kw)
     for a, b in zip(lax_u, pal_u):
         assert b["throughput"] == pytest.approx(a["throughput"], rel=1e-5)
         assert b["median_ms"] == pytest.approx(a["median_ms"], rel=1e-4)
         assert b["p99_ms"] == pytest.approx(a["p99_ms"], rel=1e-4)
 
 
-def test_kernel_pallas_multigroup_and_faulty():
+def test_kernel_pallas_multigroup_and_faulty(monkeypatch):
     """Kernel parity holds across R (segment shapes) and under fault masks
     (down followers = +inf arrivals, the kernel's masked-slot path)."""
     from repro.faults import crash_window
+
+    def both(cfgs, grid, dur, warm):
+        outs = []
+        for kernel in ("lax", "pallas"):
+            _use_fanin(monkeypatch, kernel)
+            outs.append(vs.simulate_grid(cfgs, grid, dur, warm))
+        return outs
+
     for r in (1, 4):
         cfgs = [vs.build_config("pigpaxos", 13, pig=PigConfig(n_groups=r))]
         grid = [(0, 8, s) for s in range(4)]
-        a = vs.simulate_grid(cfgs, grid, 0.1, 0.05, kernel="lax")
-        b = vs.simulate_grid(cfgs, grid, 0.1, 0.05, kernel="pallas")
+        a, b = both(cfgs, grid, 0.1, 0.05)
         np.testing.assert_allclose(np.asarray(a["throughput"]),
                                    np.asarray(b["throughput"]), rtol=1e-5)
     masks = crash_window(5, 0.02, 0.08).to_masks(13, 0.2)
     cfgs = [vs.build_config("pigpaxos", 13, pig=PigConfig(n_groups=3),
                             masks=masks)]
     grid = [(0, 8, s) for s in range(4)]
-    a = vs.simulate_grid(cfgs, grid, 0.2, 0.0, kernel="lax")
-    b = vs.simulate_grid(cfgs, grid, 0.2, 0.0, kernel="pallas")
+    a, b = both(cfgs, grid, 0.2, 0.0)
     np.testing.assert_allclose(np.asarray(a["throughput"]),
                                np.asarray(b["throughput"]), rtol=1e-5)
 
 
-def test_resolve_kernel():
-    assert vs._resolve_kernel("auto", "epaxos") == "lax"
-    assert vs._resolve_kernel("lax", "group") == "lax"
-    assert vs._resolve_kernel("pallas", "group") == "pallas"
-    with pytest.raises(ValueError):
-        vs._resolve_kernel("nope", "group")
+def test_resolve_kernel(monkeypatch):
+    """The platform picks the fan-in: the Pallas kernel for the group
+    kernel on a TPU, the lax path otherwise; the epaxos kernel, which has
+    no grouped fan-in, always gets lax.  Both grid entries read the rule
+    through the ``_fanin`` hook, which their shared plan carries."""
+    assert vs._fanin is vs._resolve_kernel
+    for backend, want in (("tpu", "pallas"), ("cpu", "lax"), ("gpu", "lax")):
+        monkeypatch.setattr(vs.jax, "default_backend", lambda: backend)
+        assert vs._resolve_kernel("group") == want
+        assert vs._resolve_kernel("epaxos") == "lax"
+    monkeypatch.undo()
+    cfgs = [vs.build_config("pigpaxos", 9, pig=PigConfig(n_groups=2))]
+    grid = [(0, 8, 0)]
+    assert vs._plan(cfgs, grid, 0.1, 0.05, 64, 8)[1].kernel == \
+        vs._resolve_kernel("group")
+    _use_fanin(monkeypatch, "pallas")
+    assert vs._plan(cfgs, grid, 0.1, 0.05, 64, 8)[1].kernel == "pallas"
 
 
 # ------------------------------------------- one-hot relay lookups
@@ -417,10 +441,11 @@ def test_onehot_grid_equals_gather_grid(case, monkeypatch):
     import jax
 
     cfgs, grid, dur, warm, kernel = _lookup_grids()[case]
+    _use_fanin(monkeypatch, kernel)
 
     def run():
         before = vs.index_counts()
-        out = vs.simulate_grid(cfgs, grid, dur, warm, kernel=kernel)
+        out = vs.simulate_grid(cfgs, grid, dur, warm)
         after = vs.index_counts()
         return out, {k: v - before.get(k, 0) for k, v in after.items()
                      if v != before.get(k, 0)}
